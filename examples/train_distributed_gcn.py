@@ -48,6 +48,7 @@ from repro.dist.halo import (
 )
 from repro.dist.policy import ShardingPolicy
 from repro.graph.generators import make_dataset
+from repro.launch.mesh import make_halo_mesh, make_mesh
 from repro.launch.obsflags import add_obs_args, obs_session
 from repro.models.gcn import GCNConfig, gcn_forward, gcn_init
 from repro.obs import metrics as obs_metrics, trace as obs_trace
@@ -76,11 +77,11 @@ def run(args) -> None:
     hier = pods > 1
     if hier:
         axes = ("pod", "model")
-        mesh = jax.make_mesh((pods, k // pods), axes)
+        mesh = make_halo_mesh(pods, k // pods)
         print(f"devices: {k} (mesh {pods}×{k // pods}, axes {axes})")
     else:
         axes = ("model",)
-        mesh = jax.make_mesh((k,), axes)
+        mesh = make_mesh((k,), axes)
         print(f"devices: {k} (mesh axis 'model')")
 
     # ---- graph → partition → cached halo plan --------------------------------

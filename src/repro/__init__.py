@@ -17,12 +17,3 @@ Layers:
 """
 
 __version__ = "1.0.0"
-
-# Install the jax compat shims (modern `jax.shard_map` signature and
-# dict-returning `Compiled.cost_analysis` on older jax builds) as soon as any
-# repro module is imported — subprocess tests and drivers use the modern
-# spellings without importing repro.dist first. Touches no jax device state
-# (DESIGN.md §7.4).
-import repro.dist.compat as _compat  # noqa: F401  (shims install on import)
-
-del _compat

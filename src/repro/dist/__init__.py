@@ -27,7 +27,6 @@ This package makes that contract executable on a JAX mesh:
            :func:`apply_delta_to_graph`, the order-preserving `GraphData`
            application the serving layer's scoped invalidation builds on.
 """
-from repro.dist.compat import ensure_shard_map
 from repro.dist.delta import DeltaPlanner, GraphDelta, apply_delta_to_graph
 from repro.dist.halo import (
     HaloPlan,
@@ -51,5 +50,4 @@ __all__ = [
     "GraphDelta",
     "DeltaPlanner",
     "apply_delta_to_graph",
-    "ensure_shard_map",
 ]

@@ -73,13 +73,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.quant import dequantize_payload, quantize_payload
-from repro.dist.compat import ensure_shard_map
 from repro.graph.ops import aggregate
 from repro.graph.structure import blocked_adjacency
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
-
-ensure_shard_map()
 
 __all__ = [
     "HaloPlan",

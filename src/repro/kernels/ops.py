@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU they
-compile natively. `interpret=None` auto-detects the backend.
+On a TPU the kernels always compile natively; interpret mode is for the CPU
+only, where `interpret=None` picks it.
 
 The graph kernels (`bsr_spmm`, `fused_gcn_layer`) carry custom VJPs so the
 training path can differentiate straight through the pallas_call: the
@@ -32,7 +32,12 @@ def on_tpu() -> bool:
 
 
 def _auto(interpret: bool | None) -> bool:
-    return (not on_tpu()) if interpret is None else interpret
+    if on_tpu():
+        if interpret:
+            raise ValueError("Pallas interpret mode is for the CPU only; "
+                             "on a TPU the kernels compile natively")
+        return False
+    return True if interpret is None else interpret
 
 
 def _pick_f_tile(F: int) -> int:

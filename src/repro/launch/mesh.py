@@ -10,8 +10,10 @@ hierarchical (pod, model) halo exchange of full-graph GNN cells
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
+    "make_mesh",
     "make_production_mesh",
     "make_local_mesh",
     "make_halo_mesh",
@@ -20,15 +22,29 @@ __all__ = [
 ]
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` makes ``Explicit`` axes by default, under which
+    ``with_sharding_constraint`` (``ShardingPolicy.constrain``) and shard_map
+    bodies closing over mesh-sharded values both raise. Every mesh of this
+    repo — drivers, examples, test scripts — is built here. ``devices``
+    defaults to ``jax.devices()``; pass a described topology's devices to
+    compile for a chip that is not attached."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), (AxisType.Auto,) * len(axes), devices=devices
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names (CPU tests/examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_halo_mesh(pods: int, devices_per_pod: int, *, pod_map=None):
@@ -47,7 +63,7 @@ def make_halo_mesh(pods: int, devices_per_pod: int, *, pod_map=None):
         from repro.dist.halo import validate_pod_map
 
         validate_pod_map(pod_map, pods * devices_per_pod, pods)
-    return jax.make_mesh((pods, devices_per_pod), ("pod", "model"))
+    return make_mesh((pods, devices_per_pod), ("pod", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -4,6 +4,10 @@ and online GCN node-query serving with the hot-neighbor cache (DESIGN.md §9).
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b --tokens 32
     PYTHONPATH=src python -m repro.launch.serve --arch deepfm --requests 4
     PYTHONPATH=src python -m repro.launch.serve --arch coin-gcn --queries 64
+    PYTHONPATH=src python -m repro.launch.serve --arch coin_gcn --shape nell --queries 64
+
+``--shape`` serves coin_gcn's published config on that Table-I dataset at full
+size instead of the reduced config on a 2000-node graph.
 """
 from __future__ import annotations
 
@@ -15,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_arch
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.obsflags import add_obs_args, obs_session
 
 
@@ -61,23 +66,32 @@ def build_graph_engine(
     seed: int = 0,
     n_nodes: int = 2000,
     n_edges: int = 12000,
+    shape: str | None = None,
 ):
-    """A small serving engine for a GNN arch on a citation-like graph.
+    """A serving engine for a GNN arch: the reduced config on a small
+    citation-like graph, or — with ``shape`` — coin_gcn's published config
+    on that Table-I dataset at full size.
 
     Returns (engine, graph). Shared by the CLI, the example, and the serve
     benchmark so they exercise one code path.
     """
     from repro.core.partition import partition_graph
-    from repro.graph.generators import citation_like
+    from repro.graph.generators import citation_like, make_dataset
     from repro.serve.graph import GraphBatcher
 
-    cfg = spec.make_reduced()
+    if shape is not None and spec.arch_id != "coin_gcn":
+        raise SystemExit(f"--shape serves coin_gcn on a Table-I dataset; "
+                         f"{spec.arch_id} has no dataset for {shape!r}")
+    cfg = spec.make_reduced() if shape is None else spec.make_config(spec.shapes[shape])
     part = None
     if spec.arch_id == "coin_gcn":
         from repro.models.gcn import gcn_init
 
         d_in, n_out = cfg.layer_dims[0], cfg.layer_dims[-1]
-        graph = citation_like(n_nodes, n_edges, d_in, n_out, seed=seed)
+        if shape is None:
+            graph = citation_like(n_nodes, n_edges, d_in, n_out, seed=seed)
+        else:
+            _, graph = make_dataset(shape, seed=seed)
         params = gcn_init(jax.random.PRNGKey(seed), cfg)
         model = "gcn"
     elif spec.arch_id == "pna":
@@ -117,10 +131,12 @@ def serve_graph(
     n_parts: int = 4,
     seed: int = 0,
     relocalize_threshold: float = 0.0,
-) -> None:
+    shape: str | None = None,
+):
     """Serve ``n_queries`` node-classification queries (degree-weighted, so
     hub neighborhoods are hot — the COIN access pattern) and report latency
-    plus hot-neighbor-cache accounting.
+    plus hot-neighbor-cache accounting. Returns the engine, whose
+    ``finished`` queries hold the served logits.
 
     With ``relocalize_threshold`` > 0 a churn burst is injected halfway
     through the stream: each delta goes to both the engine
@@ -132,7 +148,7 @@ def serve_graph(
 
     engine, graph = build_graph_engine(
         spec, batch_seeds=batch_seeds, fanout=fanout,
-        cache_capacity=cache_capacity, n_parts=n_parts, seed=seed,
+        cache_capacity=cache_capacity, n_parts=n_parts, seed=seed, shape=shape,
     )
     planner = None
     if relocalize_threshold > 0 and engine.partition is not None:
@@ -175,6 +191,7 @@ def serve_graph(
             f"{c['capacity']}, evictions {c['evictions']}, "
             f"rows saved {c['rows_saved']}, bytes saved {c['bytes_saved']/1e3:.1f} kB"
         )
+    return engine
 
 
 def _serve_churn_burst(engine, planner, graph, seed: int, rounds: int = 8) -> int:
@@ -202,10 +219,14 @@ def _serve_churn_burst(engine, planner, graph, seed: int, rounds: int = 8) -> in
     return fired
 
 
-def main(argv=None) -> None:
+def main(argv=None):
+    """Serve the arch; for a GNN returns the `GraphBatcher` that served."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     help=f"one of {', '.join(ALL_ARCHS)} (hyphen/underscore both fine)")
+    ap.add_argument("--shape", default=None,
+                    help="registry shape to serve at full size "
+                         "(coin_gcn: a Table-I dataset, e.g. nell)")
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--queries", type=int, default=64, help="graph node queries to serve")
@@ -220,18 +241,25 @@ def main(argv=None) -> None:
     add_obs_args(ap)
     args = ap.parse_args(argv)
     spec = get_arch(args.arch)
+    if args.shape is not None:
+        if spec.family != "gnn":
+            ap.error(f"--shape is for GNN archs; {args.arch} is {spec.family}")
+        if args.shape not in spec.shapes:
+            ap.error(f"--shape {args.shape!r}: {args.arch} has {sorted(spec.shapes)}")
+    use_compile_cache()
     with obs_session(args):
         if spec.family == "lm":
             serve_lm(spec, args.tokens)
         elif spec.family == "recsys":
             serve_recsys(spec, args.requests)
         elif spec.family == "gnn":
-            serve_graph(
+            return serve_graph(
                 spec, args.queries,
                 batch_seeds=args.batch_seeds, fanout=args.fanout,
                 cache_capacity=0 if args.no_cache else args.cache_capacity,
                 n_parts=args.parts,
                 relocalize_threshold=args.relocalize_threshold,
+                shape=args.shape,
             )
         else:
             raise SystemExit(

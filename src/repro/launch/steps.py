@@ -42,7 +42,7 @@ from repro.launch import shardings as sh
 from repro.launch.mesh import data_axes
 from repro.train.optimizer import adamw
 
-__all__ = ["Cell", "build_cell"]
+__all__ = ["Cell", "build_cell", "gnn_loss_fn", "halo_apply", "halo_loss_fn", "gcn_device_logits"]
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -256,9 +256,20 @@ def _opt_specs(opt_abs, p_specs):
 
 
 # ======================================================================== GNN
-def _gnn_loss_fn(arch_id: str, cfg, policy: ShardingPolicy, n_loss_nodes: int | None = None):
-    """Regression loss over model output (sliced to the first ``n_loss_nodes``
-    rows for sampled blocks — losses are computed on the seed nodes only)."""
+def _bsr_tables(batch: dict, prefix: str = "bsr_"):
+    """The ``(vals, cols, lens)`` blocked-adjacency triple a coin_gcn batch
+    carries under ``<prefix>vals``/``cols``/``lens`` (None without one)."""
+    if prefix + "vals" not in batch:
+        return None
+    return tuple(batch[prefix + k] for k in ("vals", "cols", "lens"))
+
+
+def gnn_loss_fn(arch_id: str, cfg, policy: ShardingPolicy, n_loss_nodes: int | None = None):
+    """``loss(params, batch)`` of a GNN arch on one device: cross-entropy
+    for coin_gcn (``backend="bsr"`` reads its blocked adjacency from the
+    batch's ``bsr_vals``/``bsr_cols``/``bsr_lens``), regression elsewhere
+    (sliced to the first ``n_loss_nodes`` rows for sampled blocks — losses
+    are computed on the seed nodes only)."""
 
     def _mse(pred, target):
         if n_loss_nodes is not None:
@@ -307,6 +318,7 @@ def _gnn_loss_fn(arch_id: str, cfg, policy: ShardingPolicy, n_loss_nodes: int | 
             return gcn_loss(
                 params, batch["feats"], batch["senders"], batch["receivers"],
                 batch["edge_weight"], batch["labels"], batch["label_mask"], cfg, policy,
+                adjacency=_bsr_tables(batch),
             )
     else:
         raise KeyError(arch_id)
@@ -474,6 +486,24 @@ def _shape_halo_plan(n: int, e: int, k: int, pods: int = 1):
     )
 
 
+def gcn_device_logits(cfg):
+    """``logits(params, b, pol)`` of coin_gcn on one device's HaloPlan block
+    (a `halo_apply` device function). ``backend="bsr"`` reads the split pair
+    of `repro.dist.halo.plan_split_blocked_adjacency` from ``b``: interior
+    ``bsr_`` and boundary ``bsr_b`` tables — the overlapped schedule, where
+    interior tiles aggregate the local block while the boundary tables
+    consume the halo exchange."""
+    from repro.models.gcn import gcn_forward
+
+    def logits(params, b, pol):
+        return gcn_forward(
+            params, b["feats"], b["senders"], b["receivers"], b["edge_w"], cfg, pol,
+            adjacency=_bsr_tables(b), adjacency_boundary=_bsr_tables(b, "bsr_b"),
+        ).astype(F32)
+
+    return logits
+
+
 def _gnn_halo_device_loss(arch_id: str, cfg):
     """Per-device (weighted_sum, weight) of the arch's loss over one block.
 
@@ -486,23 +516,7 @@ def _gnn_halo_device_loss(arch_id: str, cfg):
     def device_loss(params, b, pol):
         edge_mask = (b["edge_w"] > 0).astype(F32)
         if arch_id == "coin_gcn":
-            from repro.models.gcn import gcn_forward
-
-            adjacency = (
-                (b["bsr_vals"], b["bsr_cols"], b["bsr_lens"])
-                if "bsr_vals" in b else None
-            )
-            # Split pair (interior adjacency above + boundary tables below):
-            # the overlapped schedule — interior tiles aggregate the local
-            # block while the boundary tables consume the halo exchange.
-            adjacency_boundary = (
-                (b["bsr_bvals"], b["bsr_bcols"], b["bsr_blens"])
-                if "bsr_bvals" in b else None
-            )
-            logits = gcn_forward(
-                params, b["feats"], b["senders"], b["receivers"], b["edge_w"], cfg, pol,
-                adjacency=adjacency, adjacency_boundary=adjacency_boundary,
-            ).astype(F32)
+            logits = gcn_device_logits(cfg)(params, b, pol)
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(logits, b["labels"][:, None], axis=-1)[:, 0]
             return ((lse - gold) * b["label_mask"]).sum(), b["label_mask"].sum()
@@ -539,6 +553,65 @@ def _gnn_halo_device_loss(arch_id: str, cfg):
         return (sq * b["node_mask"]).sum(), b["node_mask"].sum() * pred.shape[-1]
 
     return device_loss
+
+
+def _halo_spec_axes(mesh):
+    from repro.launch.mesh import halo_axes
+
+    axes = halo_axes(mesh)
+    return axes if len(axes) > 1 else "model"
+
+
+def halo_apply(device_fn, mesh, policy: ShardingPolicy):
+    """``f(params, batch)`` running ``device_fn(params, b, pol)`` on every
+    device of a HaloPlan layout, inside shard_map over the mesh's halo axes
+    (("model",) flat, ("pod", "model") hierarchical).
+
+    Every batch leaf carries one leading slice per device (``(k, n_local,
+    …)`` node arrays, ``(k, e_local)`` edge arrays, the plan's export rows
+    and, for coin_gcn ``backend="bsr"``, the per-shard blocked tables);
+    ``b`` is this device's slice and ``pol`` the policy with its export rows
+    bound. Outputs stack along a leading device axis."""
+    spec_axes = _halo_spec_axes(mesh)
+    hier = isinstance(spec_axes, tuple)
+
+    def f(params, batch):
+        keys = sorted(batch)
+
+        def body(*args):
+            b = {kk: a[0] for kk, a in zip(keys, args)}
+            if hier:
+                pol = policy.bind_halo(send_loc=b["send_loc"], send_rem=b["send_rem"])
+            else:
+                pol = policy.bind_halo(b["send_idx"])
+            return jax.tree_util.tree_map(lambda o: o[None], device_fn(params, b, pol))
+
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(P(spec_axes),) * len(keys), out_specs=P(spec_axes),
+            # pallas_call (the backend="bsr" blocked aggregation) has no
+            # replication rule; psum-combined scalars make rep moot anyway.
+            check_vma=False,
+        )(*[batch[kk] for kk in keys])
+
+    return f
+
+
+def halo_loss_fn(arch_id: str, cfg, mesh, policy: ShardingPolicy):
+    """``loss(params, batch)`` of a full-graph GNN over a HaloPlan layout
+    (`halo_apply`): per-device sums psum-combined, so it equals the
+    one-device loss on the whole graph."""
+    spec_axes = _halo_spec_axes(mesh)
+    device_loss = _gnn_halo_device_loss(arch_id, cfg)
+
+    def per_device(params, b, pol):
+        wsum, wcnt = device_loss(params, b, pol)
+        return jax.lax.psum(wsum, spec_axes) / jnp.maximum(
+            jax.lax.psum(wcnt, spec_axes), 1.0
+        )
+
+    f = halo_apply(per_device, mesh, policy)
+    return lambda params, batch: f(params, batch).mean()
 
 
 def _gnn_halo_batch_abstract(
@@ -649,33 +722,11 @@ def _gnn_halo_cell(
     p_specs = sh.replicated_specs(params_abs)
     p_shard = sh.tree_named(mesh, p_specs)
     batch_abs = _gnn_halo_batch_abstract(spec.arch_id, shape, cfg, plan, bsr_stats)
-    keys = sorted(batch_abs)
     batch_spec = {
         kk: sh.named(mesh, P(spec_axes, *([None] * (len(v.shape) - 1))))
         for kk, v in batch_abs.items()
     }
-    device_loss = _gnn_halo_device_loss(spec.arch_id, cfg)
-
-    def total_loss(params, batch):
-        def body(*args):
-            b = {kk: a[0] for kk, a in zip(keys, args)}
-            if hier:
-                pol = policy.bind_halo(send_loc=b["send_loc"], send_rem=b["send_rem"])
-            else:
-                pol = policy.bind_halo(b["send_idx"])
-            wsum, wcnt = device_loss(params, b, pol)
-            loss = jax.lax.psum(wsum, spec_axes) / jnp.maximum(
-                jax.lax.psum(wcnt, spec_axes), 1.0
-            )
-            return loss[None]
-        f = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(spec_axes),) * len(keys), out_specs=P(spec_axes),
-            # pallas_call (the backend="bsr" blocked aggregation) has no
-            # replication rule; psum-combined scalars make rep moot anyway.
-            check_vma=False,
-        )
-        return f(*[batch[kk] for kk in keys]).mean()
+    total_loss = halo_loss_fn(spec.arch_id, cfg, mesh, policy)
 
     opt = adamw(lr=1e-3)
     opt_abs = jax.eval_shape(opt.init, params_abs)
@@ -763,7 +814,7 @@ def _gnn_cell(
     params_abs = _gnn_params(spec.arch_id, cfg, dtype)
     p_specs = sh.replicated_specs(params_abs)
     p_shard = sh.tree_named(mesh, p_specs)
-    loss_fn = _gnn_loss_fn(
+    loss_fn = gnn_loss_fn(
         spec.arch_id, cfg, policy, n_loss_nodes=shape.batch_nodes if sampled else None
     )
     batch_abs = _gnn_batch_abstract(spec.arch_id, shape, cfg, n_blocks, pad_mult=msize)

@@ -2,12 +2,15 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch pna --steps 50
     PYTHONPATH=src python -m repro.launch.train --arch olmoe-1b-7b --steps 20
+    PYTHONPATH=src python -m repro.launch.train --arch coin_gcn --shape nell --steps 5
 
-Runs the REDUCED config on the local device(s) — the full configs are
-exercised by the dry-run (`repro.launch.dryrun`) and, on real hardware, by
-pointing `make_production_mesh` at the pod. The driver wires the complete
-substrate: synthetic data stream → jitted train step → AdamW → checkpointing
-→ straggler monitor, and resumes from the latest checkpoint on restart.
+Runs the REDUCED config on the local device(s) unless ``--shape`` names a
+registry shape of the arch: coin_gcn then trains its published config on
+that Table-I dataset at full size (`repro.graph.generators.make_dataset`).
+The full configs of the other archs are exercised by the dry-run
+(`repro.launch.dryrun`). The driver wires the complete substrate: synthetic
+data stream → jitted train step → AdamW → checkpointing → straggler monitor,
+and resumes from the latest checkpoint on restart.
 """
 from __future__ import annotations
 
@@ -18,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_arch
+from repro.graph.generators import make_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.obsflags import add_obs_args, obs_session
 from repro.train.loop import Trainer, TrainerConfig
 from repro.train.optimizer import adamw
@@ -38,36 +43,65 @@ def _lm_setup(spec, batch=4, seq=64):
     return params, (lambda p, b: lm_loss(p, b, cfg)), batches
 
 
-def _gnn_setup(spec, relocalize_threshold: float = 0.0):
-    from repro.graph.generators import citation_like
-    from repro.launch.steps import _gnn_loss_fn
-    from repro.dist.policy import NO_POLICY
-
-    cfg = spec.make_reduced()
-    d_in = getattr(cfg, "d_in", None) or getattr(cfg, "input_dim", 8)
-    g = citation_like(256, 1024, seed=0)
-    rng = np.random.default_rng(0)
-    if spec.arch_id == "coin_gcn":
-        d_in = cfg.layer_dims[0]
-    base = {
-        "feats": jnp.asarray(rng.standard_normal((g.n_nodes, d_in)), jnp.float32),
+def _shape_batch(spec, shape: str):
+    """coin_gcn's published config on the Table-I dataset ``shape`` at full
+    size, with self-loops and Kipf–Welling weights. Returns (cfg, graph,
+    batch); features are cast to fp32 on the host, so the float16 that
+    `make_dataset` emits for large sets never reaches the device."""
+    if spec.arch_id != "coin_gcn":
+        raise SystemExit(f"--shape trains coin_gcn on a Table-I dataset; "
+                         f"{spec.arch_id} has no dataset for {shape!r}")
+    cfg = spec.make_config(spec.shapes[shape])
+    _, g = make_dataset(shape)
+    if g.features.shape[1] != cfg.layer_dims[0]:
+        raise ValueError(f"{shape}: features are {g.features.shape[1]} wide, "
+                         f"the config expects {cfg.layer_dims[0]}")
+    g = g.with_self_loops()
+    batch = {
+        "feats": jnp.asarray(g.features.astype(np.float32)),
         "senders": jnp.asarray(g.edge_index[0]),
         "receivers": jnp.asarray(g.edge_index[1]),
+        "edge_weight": jnp.asarray(g.sym_normalized_weights()),
+        "labels": jnp.asarray(g.labels),
+        "label_mask": jnp.ones(g.n_nodes),
     }
-    if spec.arch_id in ("egnn", "equiformer-v2"):
-        base["pos"] = jnp.asarray(rng.standard_normal((g.n_nodes, 3)), jnp.float32)
-    if spec.arch_id == "graphcast":
-        base["edge_feats"] = jnp.asarray(rng.standard_normal((g.n_edges, cfg.d_edge_in)), jnp.float32)
-    if spec.arch_id == "coin_gcn":
-        base["edge_weight"] = jnp.ones(g.n_edges)
-        base["labels"] = jnp.asarray(g.labels)
-        base["label_mask"] = jnp.ones(g.n_nodes)
-    else:
-        n_out = cfg.n_vars if spec.arch_id == "graphcast" else cfg.d_out
-        base["target"] = jnp.asarray(rng.standard_normal((g.n_nodes, n_out)) * 0.1, jnp.float32)
-    from repro.launch.steps import _gnn_params  # params via real init
+    return cfg, g, batch
 
-    loss = _gnn_loss_fn(spec.arch_id, cfg, NO_POLICY)
+
+def _gnn_setup(spec, relocalize_threshold: float = 0.0, shape: str | None = None):
+    from repro.graph.generators import citation_like
+    from repro.launch.steps import gnn_loss_fn
+    from repro.dist.policy import NO_POLICY
+
+    if shape is not None:
+        cfg, g, base = _shape_batch(spec, shape)
+    else:
+        cfg = spec.make_reduced()
+        d_in = getattr(cfg, "d_in", None) or getattr(cfg, "input_dim", 8)
+        g = citation_like(256, 1024, seed=0)
+        rng = np.random.default_rng(0)
+        if spec.arch_id == "coin_gcn":
+            d_in = cfg.layer_dims[0]
+        base = {
+            "feats": jnp.asarray(rng.standard_normal((g.n_nodes, d_in)), jnp.float32),
+            "senders": jnp.asarray(g.edge_index[0]),
+            "receivers": jnp.asarray(g.edge_index[1]),
+        }
+        if spec.arch_id in ("egnn", "equiformer-v2"):
+            base["pos"] = jnp.asarray(rng.standard_normal((g.n_nodes, 3)), jnp.float32)
+        if spec.arch_id == "graphcast":
+            base["edge_feats"] = jnp.asarray(
+                rng.standard_normal((g.n_edges, cfg.d_edge_in)), jnp.float32)
+        if spec.arch_id == "coin_gcn":
+            base["edge_weight"] = jnp.ones(g.n_edges)
+            base["labels"] = jnp.asarray(g.labels)
+            base["label_mask"] = jnp.ones(g.n_nodes)
+        else:
+            n_out = cfg.n_vars if spec.arch_id == "graphcast" else cfg.d_out
+            base["target"] = jnp.asarray(
+                rng.standard_normal((g.n_nodes, n_out)) * 0.1, jnp.float32)
+
+    loss = gnn_loss_fn(spec.arch_id, cfg, NO_POLICY)
     params = _init_gnn(spec.arch_id, cfg)
 
     if relocalize_threshold <= 0:
@@ -161,9 +195,13 @@ def _recsys_setup(spec, batch=256):
     return params, (lambda p, b: deepfm_loss(p, b["ids"], b["labels"], cfg)), batches
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> list[float]:
+    """Train ``--steps`` steps; returns the per-step losses."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ALL_ARCHS)
+    ap.add_argument("--shape", default=None,
+                    help="registry shape of the arch to train at full size "
+                         "(coin_gcn: a Table-I dataset, e.g. nell)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=1e-3)
@@ -175,11 +213,17 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     spec = get_arch(args.arch)
+    if args.shape is not None:
+        if spec.family != "gnn":
+            ap.error(f"--shape is for GNN archs; {args.arch} is {spec.family}")
+        if args.shape not in spec.shapes:
+            ap.error(f"--shape {args.shape!r}: {args.arch} has {sorted(spec.shapes)}")
+    use_compile_cache()
     setup = {"lm": _lm_setup, "gnn": _gnn_setup, "recsys": _recsys_setup}[spec.family]
     with obs_session(args):
         if spec.family == "gnn":
             params, loss_fn, batches = _gnn_setup(
-                spec, relocalize_threshold=args.relocalize_threshold)
+                spec, relocalize_threshold=args.relocalize_threshold, shape=args.shape)
         else:
             params, loss_fn, batches = setup(spec)
         tr = Trainer(
@@ -194,6 +238,11 @@ def main(argv=None) -> None:
             tr.resume()
         losses = tr.fit(batches(), max_steps=args.steps)
         print(f"{args.arch}: loss {losses[0]:.4f} → {losses[-1]:.4f} over {len(losses)} steps")
+        dts = tr.step_seconds
+        if len(dts) > 1:
+            print(f"  step seconds: first {dts[0]:.3f} (compile included), "
+                  f"median of the rest {float(np.median(dts[1:])):.4f}")
+    return losses
 
 
 if __name__ == "__main__":

@@ -310,13 +310,9 @@ def jax_profiler_trace(log_dir: str):
 
     Span tracing answers "does the collective overlap the interior
     compute"; the jax profiler answers "what is XLA doing inside that
-    span". Degrades to a no-op if the profiler is unavailable (e.g.
-    stripped CPU builds)."""
-    try:
-        import jax.profiler as _prof
+    span". A profiler that cannot start raises: a run asked for a device
+    trace must not finish without one."""
+    import jax.profiler as _prof
 
-        ctx = _prof.trace(log_dir)
-    except Exception:  # noqa: BLE001 - profiler availability is best-effort
-        ctx = contextlib.nullcontext()
-    with ctx:
+    with _prof.trace(log_dir):
         yield

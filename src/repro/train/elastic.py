@@ -35,7 +35,9 @@ class MeshPlan:
 
     def build(self, devices: Sequence[Any] | None = None):
         if devices is None:
-            return jax.make_mesh(self.shape, self.axes)
+            from repro.launch.mesh import make_mesh
+
+            return make_mesh(self.shape, self.axes)
         arr = np.asarray(devices[: self.n_devices]).reshape(self.shape)
         return jax.sharding.Mesh(arr, self.axes)
 

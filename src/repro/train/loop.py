@@ -57,6 +57,7 @@ class Trainer:
         )
         self.step = 0
         self.straggler_events: list[dict] = []
+        self.step_seconds: list[float] = []     # host clock, one per step
         self._ema_dt: float | None = None
         self._loss_fn = loss_fn
         self._step_fn = self._build_step(donate)
@@ -136,6 +137,7 @@ class Trainer:
             dt = time.perf_counter() - t0
             self.step += 1
             losses.append(loss)
+            self.step_seconds.append(dt)
             if _obs_metrics.enabled():
                 _obs_metrics.inc("train.steps")
                 _obs_metrics.observe("train.step_ms", dt * 1e3)

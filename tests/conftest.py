@@ -1,39 +1,14 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see 1 device (the dry-run sets its own 512-device flag in a fresh process).
-
-Also registers the vendored `hypothesis` fallback (tests/_hypothesis_stub.py)
-when the real package is not installed, so the property tests run in minimal
-environments (e.g. the pinned CPU container). Install `hypothesis`
-(requirements-dev.txt) to get real shrinking and coverage.
 """
-import importlib.util
-import pathlib
-import sys
-
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401  (the real thing wins when available)
-except ModuleNotFoundError:
-    _stub_path = pathlib.Path(__file__).with_name("_hypothesis_stub.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis.strategies"] = _mod.strategies
+from hypothesis import settings as _h_settings
 
 # Flaky-seed hygiene: property tests must reproduce locally from a CI log.
-# Real hypothesis gets a pinned derandomize profile; the vendored stub is
-# already derandomized (per-test crc32 seeds) and accepts the same calls.
-from hypothesis import settings as _h_settings  # noqa: E402
-
-try:
-    _h_settings.register_profile(
-        "repro-derandomize", _h_settings(derandomize=True, deadline=None))
-    _h_settings.load_profile("repro-derandomize")
-except Exception:  # pragma: no cover — exotic hypothesis versions
-    pass
+_h_settings.register_profile(
+    "repro-derandomize", _h_settings(derandomize=True, deadline=None))
+_h_settings.load_profile("repro-derandomize")
 
 
 def pytest_addoption(parser):

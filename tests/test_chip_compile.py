@@ -1,0 +1,154 @@
+"""Compile the main path for a described TPU v5e — no chip needed.
+
+Interpret-mode Pallas (every other kernel test) cannot see what the chip's
+compiler refuses: unaligned slices, too much VMEM, a program that does not
+fit. These tests compile the kernels and the train steps at real widths for
+a `v5e:2x2` topology that is described, not attached, and check that the
+compiled program holds a native kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file. Keep these tests in this one file for the same reason.
+The compiles keep the persistent compile cache off — an entry written for a
+described chip cannot be read back without one.
+
+The train steps compile with quantization off: the published config's
+4-bit QAT adds a `lax.top_k` calibration over every input activation, which
+takes the host compiler about 45 s at pubmed width; `chip_smoke.py` runs it.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# pubmed's blocked adjacency as the kernels see it: 155 block rows of 128,
+# up to 147 nonzero tiles each.
+R, T, B = 155, 147, 128
+N_PUBMED, E_PUBMED = 19717, 88651
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")      # no compiler logs outside the repo
+        try:
+            desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield desc
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """Kernel wrappers pick native Pallas, as they do on a TPU backend."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _tables(sharding, dtype):
+    return (_sds((R, T, B, B), dtype, sharding), _sds((R, T), jnp.int32, sharding),
+            _sds((R,), jnp.int32, sharding))
+
+
+def _fp32_spec():
+    """coin_gcn's registry entry with quantization off (see module doc)."""
+    from repro.configs import get_arch
+    from repro.core.quant import QuantConfig
+
+    spec = get_arch("coin_gcn")
+    return dataclasses.replace(spec, make_config=lambda shape=None: dataclasses.replace(
+        spec.make_config(shape), quant=QuantConfig(enabled=False)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("f", [128, 512])
+def test_bsr_spmm_compiles_at_pubmed_width(one_chip, f, dtype):
+    from repro.kernels.bsr_spmm import bsr_spmm_pallas
+
+    z = _sds((R * B, f), dtype, one_chip)
+    text = bsr_spmm_pallas.lower(
+        *_tables(one_chip, dtype), z, f_tile=f, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("order,f_in", [
+    ("feature_first", 512),       # pubmed (500 → 16), lanes padded
+    ("feature_first", 8832),      # extcora (8710 → 16)
+    ("aggregation_first", 512),
+])
+def test_fused_gcn_layer_compiles(one_chip, order, f_in, dtype):
+    from repro.kernels.fused_gcn import fused_gcn_layer_pallas
+
+    f_out = 128                   # 16 hidden units, padded to one lane tile
+    args = (*_tables(one_chip, dtype), _sds((R * B, f_in), dtype, one_chip),
+            _sds((f_in, f_out), dtype, one_chip), _sds((1, f_out), dtype, one_chip))
+    text = fused_gcn_layer_pallas.lower(
+        *args, order=order, relu=True, f_tile=f_out, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_coin_gcn_bsr_train_step_compiles_at_pubmed_width(one_chip, native):
+    from repro.dist.policy import NO_POLICY
+    from repro.launch.steps import gnn_loss_fn
+    from repro.models.gcn import gcn_init
+    from repro.train.loop import Trainer
+    from repro.train.optimizer import adamw
+
+    spec = _fp32_spec()
+    cfg = dataclasses.replace(spec.make_config(spec.shapes["pubmed"]), backend="bsr")
+    tr = Trainer(gnn_loss_fn("coin_gcn", cfg, NO_POLICY), adamw(1e-3),
+                 gcn_init(jax.random.PRNGKey(0), cfg))
+    n, e = N_PUBMED, E_PUBMED + N_PUBMED          # + self-loops
+    vals, cols, lens = _tables(one_chip, jnp.float32)
+    batch = {
+        "feats": _sds((n, cfg.layer_dims[0]), jnp.float32, one_chip),
+        "senders": _sds((e,), jnp.int32, one_chip),
+        "receivers": _sds((e,), jnp.int32, one_chip),
+        "edge_weight": _sds((e,), jnp.float32, one_chip),
+        "labels": _sds((n,), jnp.int32, one_chip),
+        "label_mask": _sds((n,), jnp.float32, one_chip),
+        "bsr_vals": vals, "bsr_cols": cols, "bsr_lens": lens,
+    }
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    compiled = tr._step_fn.lower(
+        abstract(tr.params), abstract(tr.opt_state), None, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes < 16e9
+
+
+def test_flat_halo_train_step_compiles_on_four_chips(topo, native):
+    from repro.launch.mesh import make_mesh
+    from repro.launch.steps import build_cell
+
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+    spec = _fp32_spec()
+    cell = build_cell(spec, spec.shapes["pubmed"], mesh, optimized=True)
+    assert cell.comm == "halo" and cell.halo_plan.k == 4 and cell.bsr_stats is not None
+    text = cell.lower(mesh).compile().as_text()
+    assert "tpu_custom_call" in text

@@ -32,12 +32,13 @@ from repro.core.partition import partition_graph
 from repro.dist.halo import get_halo_plan, relocate_node_array, restore_node_array
 from repro.dist.policy import NO_POLICY, ShardingPolicy
 from repro.graph.generators import citation_like
+from repro.launch.mesh import make_mesh
 
 g = citation_like(400, 2400, seed=5)
 w = np.abs(np.random.default_rng(0).standard_normal(g.n_edges)).astype(np.float32) + 0.1
 part = partition_graph(g.n_nodes, g.edge_index, 8, method="bfs", seed=0, refine=True)
 plan = get_halo_plan(part, g.edge_index, w)
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 si, sl, rl, ew = plan.device_arrays()
 x = np.random.default_rng(1).standard_normal((g.n_nodes, 16)).astype(np.float32)
 xb = jnp.asarray(relocate_node_array(plan, x))
@@ -151,8 +152,9 @@ import jax
 from repro.configs import get_arch
 from repro.launch.dryrun import collective_bytes, exchange_accounting
 from repro.launch.steps import build_cell
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 spec = get_arch("pna")
 shape = spec.shapes["full_graph_sm"]
 cell = build_cell(spec, shape, mesh)                    # the default

@@ -13,6 +13,7 @@ from repro.core.partition import partition_graph
 from repro.dist.halo import build_halo_plan, halo_aggregate, halo_exchange
 from repro.graph.generators import citation_like
 from repro.graph.ops import aggregate
+from repro.launch.mesh import make_mesh
 
 
 # ------------------------------------------------------------ plan properties
@@ -125,7 +126,7 @@ def test_halo_plan_custom_weights_and_zero_weight_edges():
 def _one_device_mesh():
     if jax.device_count() < 1:  # pragma: no cover
         pytest.skip("no devices")
-    return jax.make_mesh((1,), ("model",))
+    return make_mesh((1,), ("model",))
 
 
 @pytest.mark.parametrize("via", ["all_gather", "ppermute"])
@@ -279,7 +280,7 @@ def test_policy_constrain_noop_and_named():
 
     x = jnp.ones((4, 4))
     assert NO_POLICY.constrain(x, "anything") is x
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     pol = ShardingPolicy(mesh=mesh, specs={"h": P("model", None)})
     assert pol.constrain(x, "unregistered") is x
     y = pol.constrain(x, "h")                      # applies, values unchanged

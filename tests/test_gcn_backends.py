@@ -193,6 +193,7 @@ from repro.core.partition import partition_graph
 from repro.dist.halo import get_halo_plan, plan_blocked_adjacency, plan_blocked_shape, relocate_node_array, restore_node_array
 from repro.dist.policy import NO_POLICY, ShardingPolicy
 from repro.graph.generators import citation_like
+from repro.launch.mesh import make_mesh
 from repro.models.gcn import GCNConfig, gcn_forward, gcn_init
 
 g = citation_like(400, 2400, seed=5)
@@ -203,7 +204,7 @@ ba = plan_blocked_adjacency(plan)
 shp = plan_blocked_shape(plan)
 assert shp["max_nnzb"] == ba.max_nnzb and shp["nnz_blocks"] == ba.nnz_blocks
 assert plan_blocked_adjacency(plan) is ba          # cached next to the plan
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 si, sl, rl, ew = plan.device_arrays()
 bv, bc, bl = ba.device_arrays()
 x = np.random.default_rng(1).standard_normal((g.n_nodes, 16)).astype(np.float32)
@@ -236,7 +237,7 @@ assert plan_h.neighbor_table_rows == plan_h.n_local + plan_h.k_model * plan_h.bl
 ba_h = plan_blocked_adjacency(plan_h)
 assert ba_h.n_cols == plan_h.neighbor_table_rows
 assert int(plan_h.senders_l.max()) < ba_h.n_cols
-mesh_h = jax.make_mesh((2, 4), ("pod", "model"))
+mesh_h = make_mesh((2, 4), ("pod", "model"))
 sloc, srem, sl, rl, ew2 = plan_h.device_arrays()
 bv, bc, bl = ba_h.device_arrays()
 xb = jnp.asarray(relocate_node_array(plan_h, x))

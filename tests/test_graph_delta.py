@@ -511,6 +511,7 @@ from repro.core.partition import partition_graph
 from repro.dist.delta import DeltaPlanner, GraphDelta
 from repro.dist.halo import relocate_node_array, restore_node_array
 from repro.graph.generators import citation_like
+from repro.launch.mesh import make_mesh
 
 def w_of(ei):
     # weight = pure function of (u, v): duplicate edge instances share it,
@@ -536,8 +537,8 @@ from repro.dist.policy import NO_POLICY, ShardingPolicy
 
 pl = DeltaPlanner(part, ei, w_of(ei))
 plans = {"flat": pl.plan(), "hier": pl.plan(axes=("pod", "model"), pods=2)}
-mesh1d = jax.make_mesh((8,), ("model",))
-mesh2d = jax.make_mesh((2, 4), ("pod", "model"))
+mesh1d = make_mesh((8,), ("model",))
+mesh2d = make_mesh((2, 4), ("pod", "model"))
 AX = ("pod", "model")
 cfg = GCNConfig(layer_dims=(16, 32, 7), dataflow="feature_first")
 params = gcn_init(jax.random.PRNGKey(0), cfg)

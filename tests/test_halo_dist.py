@@ -56,6 +56,7 @@ from repro.core.partition import partition_graph
 from repro.dist.halo import build_halo_plan, halo_aggregate
 from repro.graph.generators import citation_like
 from repro.graph.ops import aggregate
+from repro.launch.mesh import make_mesh
 
 g = citation_like(500, 3000, seed=3)
 w = np.abs(np.random.default_rng(0).standard_normal(g.n_edges)).astype(np.float32)
@@ -69,7 +70,7 @@ off = 0
 for i in range(8):
     zb[i, :sizes[i]] = z[plan.perm[off:off+sizes[i]]]
     off += sizes[i]
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 si, sl, rl, ew = plan.device_arrays()
 ref = np.asarray(aggregate(jnp.asarray(z), jnp.asarray(g.edge_index[0]),
                            jnp.asarray(g.edge_index[1]), g.n_nodes, jnp.asarray(w)))

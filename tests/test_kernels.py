@@ -302,3 +302,19 @@ def test_flash_matches_model_attention():
     kern = flash_attention(qf, kf, vf, window=32, bq=64, bk=64)
     kern = kern.reshape(2, 4, s, d).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(kern), np.asarray(model_out), rtol=3e-5, atol=3e-5)
+
+
+# ------------------------------------------------------- interpret vs native
+def test_auto_is_native_whenever_the_backend_is_tpu(monkeypatch):
+    """On a TPU backend the wrappers never fall back to interpret mode —
+    not by default, and not on request; on the CPU interpret is the
+    default."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops._auto(None) is False
+    assert ops._auto(False) is False
+    with pytest.raises(ValueError, match="CPU only"):
+        ops._auto(True)
+    monkeypatch.setattr(ops, "on_tpu", lambda: False)
+    assert ops._auto(None) is True

@@ -191,6 +191,7 @@ from repro.core.partition import partition_graph
 from repro.dist.halo import build_halo_plan, get_halo_plan, relocate_node_array, restore_node_array
 from repro.dist.policy import NO_POLICY, ShardingPolicy
 from repro.graph.generators import citation_like
+from repro.launch.mesh import make_mesh
 
 g = citation_like(400, 2400, seed=5)
 w = np.abs(np.random.default_rng(0).standard_normal(g.n_edges)).astype(np.float32) + 0.1
@@ -215,7 +216,7 @@ def test_gcn_overlapped_equals_serialized_flat_subprocess():
 from repro.models.gcn import GCNConfig, gcn_forward, gcn_init
 
 plan = get_halo_plan(part, g.edge_index, w)
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 si, sl, rl, ew = plan.device_arrays()
 xb = jnp.asarray(relocate_node_array(plan, x))
 
@@ -261,7 +262,7 @@ def test_gcn_overlapped_equals_serialized_hier_subprocess():
 from repro.models.gcn import GCNConfig, gcn_forward, gcn_init
 
 plan = build_halo_plan(part, g.edge_index, w, axes=("pod", "model"), pods=2)
-mesh = jax.make_mesh((2, 4), ("pod", "model"))
+mesh = make_mesh((2, 4), ("pod", "model"))
 sloc, srem, sl, rl, ew = plan.device_arrays()
 xb = jnp.asarray(relocate_node_array(plan, x))
 
@@ -308,7 +309,7 @@ ref = np.asarray(gcn_forward(params, jnp.asarray(x), senders, receivers,
 # flat
 plan = get_halo_plan(part, g.edge_index, w)
 ia, bd = plan_split_blocked_adjacency(plan)
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 si, sl, rl, ew = plan.device_arrays()
 iv, ic, il = ia.device_arrays(); bv, bc, bl = bd.device_arrays()
 xb = jnp.asarray(relocate_node_array(plan, x))
@@ -327,7 +328,7 @@ assert err < 1e-3, ("flat", err)
 # hierarchical 2x4 with a bf16 wire on top
 plan_h = build_halo_plan(part, g.edge_index, w, axes=("pod", "model"), pods=2)
 ia, bd = plan_split_blocked_adjacency(plan_h)
-mesh_h = jax.make_mesh((2, 4), ("pod", "model"))
+mesh_h = make_mesh((2, 4), ("pod", "model"))
 sloc, srem, sl, rl, ew = plan_h.device_arrays()
 iv, ic, il = ia.device_arrays(); bv, bc, bl = bd.device_arrays()
 xb = jnp.asarray(relocate_node_array(plan_h, x))
@@ -358,7 +359,7 @@ def test_pna_payload_bf16_subprocess():
 from repro.models.pna import PNAConfig, pna_forward, pna_init
 
 plan = get_halo_plan(part, g.edge_index, w)
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 si, sl, rl, ew = plan.device_arrays()
 xb = jnp.asarray(relocate_node_array(plan, x))
 cfg = PNAConfig(n_layers=2, d_hidden=32, d_in=16, d_out=3)

@@ -421,7 +421,7 @@ assert np.array_equal(restore_node_array(plan_layout(A), state["m"]), x), (
     "live state lost bits across relocate_state_tree")
 
 # the maintained (re-localized) plan still serves the sharded forward
-mesh1d = jax.make_mesh((8,), ("model",))
+mesh1d = make_mesh((8,), ("model",))
 xb = jnp.asarray(relocate_node_array(planA, x))
 si, sl, rl, ew = planA.device_arrays()
 pol0 = ShardingPolicy(comm="halo")

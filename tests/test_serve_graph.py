@@ -321,11 +321,15 @@ def _serve_wave(eng, nodes):
 
 @settings(max_examples=3, deadline=None)
 @given(seed=st.integers(0, 40))
-def test_interleaved_mutations_match_no_cache_oracle(seed, delta_seed):
+def test_interleaved_mutations_match_no_cache_oracle(seed, pytestconfig):
     """Property: under ANY interleaving of {serve wave, GraphDelta,
     scoped feature update} the cached engine's logits match a fresh
     cache-less engine rebuilt on the current graph — i.e. the scoped
-    frontier-walk invalidation never leaves a stale activation behind."""
+    frontier-walk invalidation never leaves a stale activation behind.
+
+    Reads ``--delta-seed`` through the session-scoped ``pytestconfig``:
+    hypothesis refuses function-scoped fixtures under ``@given``."""
+    delta_seed = int(pytestconfig.getoption("--delta-seed"))
     rng = np.random.default_rng((seed << 10) ^ delta_seed)
     g, cfg, params = _setup(seed=seed % 5, n=40, e=160, f=8, hidden=6)
     eng = GraphBatcher(params, g, cfg, batch_seeds=4, fanout=2,

@@ -84,7 +84,8 @@ def test_dryrun_cell_smoke_subprocess():
         "import jax\n"
         "from repro.configs import get_arch\n"
         "from repro.launch.steps import build_cell\n"
-        "mesh = jax.make_mesh((4, 16), ('data', 'model'))\n"
+        "from repro.launch.mesh import make_mesh\n"
+        "mesh = make_mesh((4, 16), ('data', 'model'))\n"
         "spec = get_arch('pna')\n"
         "cell = build_cell(spec, spec.shapes['full_graph_sm'], mesh)\n"
         "compiled = cell.lower(mesh).compile()\n"
@@ -107,7 +108,8 @@ def test_compressed_psum_subprocess():
         "import jax, jax.numpy as jnp, numpy as np\n"
         "from jax.sharding import PartitionSpec as P\n"
         "from repro.train.compression import compressed_psum_mean\n"
-        "mesh = jax.make_mesh((8,), ('data',))\n"
+        "from repro.launch.mesh import make_mesh\n"
+        "mesh = make_mesh((8,), ('data',))\n"
         "x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 128)), jnp.float32)\n"
         "f = jax.shard_map(lambda s: compressed_psum_mean(s[0], 'data'),\n"
         "                  mesh=mesh, in_specs=P('data', None), out_specs=P(),\n"
@@ -144,3 +146,36 @@ def test_dryrun_results_complete_if_present():
     assert len(singles) >= 40
     bad = [r for r in singles if r["status"] == "FAIL"]
     assert not bad, [(r["arch"], r["shape"], r.get("error")) for r in bad]
+
+
+def test_train_shape_path_casts_float16_features_on_host(monkeypatch):
+    """`repro.launch.train --shape` trains coin_gcn's published config on a
+    Table-I dataset. `make_dataset` emits float16 features for the large
+    sets; the driver casts them to fp32 on the host, so no float16 array
+    reaches the device. A reduced cora with float16 features stands in for
+    the full-size set."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.configs.registry import ShapeSpec
+    from repro.graph.generators import make_dataset
+    from repro.launch import train
+
+    ds, g = make_dataset("cora", reduced=True)
+    g16 = dataclasses.replace(g, features=g.features.astype(np.float16))
+    monkeypatch.setattr(train, "make_dataset", lambda name, **kw: (ds, g16))
+    spec = get_arch("coin_gcn")
+    tiny = ShapeSpec("cora", "graph", n_nodes=ds.n_nodes, n_edges=ds.n_edges,
+                     d_feat=ds.n_features, n_out=ds.n_labels)
+    spec = dataclasses.replace(spec, shapes={"cora": tiny})
+
+    cfg, gs, batch = train._shape_batch(spec, "cora")
+    assert cfg.layer_dims == (ds.n_features, 16, ds.n_labels)
+    assert gs.n_edges == g.n_edges + g.n_nodes               # self-loops added
+    assert all(v.dtype != jnp.float16 for v in batch.values())
+    assert batch["feats"].dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(batch["feats"]), g16.features.astype(np.float32))
+
+    monkeypatch.setattr(train, "get_arch", lambda arch: spec)
+    losses = train.main(["--arch", "coin_gcn", "--shape", "cora", "--steps", "2"])
+    assert len(losses) == 2 and np.isfinite(losses).all()
