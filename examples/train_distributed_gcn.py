@@ -23,9 +23,8 @@ inter-pod fabric vs the flat plan.
 
 ``--trace out.json`` / ``--metrics out.json`` (docs/observability.md) turn
 on the `repro.obs` telemetry: the metrics snapshot mirrors the plan's wire
-accounting and cache stats, and the trace ends with an `overlap_timeline`
-demo where the ``halo.exchange.boundary_collective`` span on the ``wire``
-track visibly encloses ``overlap.interior_compute`` in Perfetto.
+accounting and cache stats, and the trace holds the training loop's spans
+(``train.step`` ⊃ ``train.dispatch``, ``train.sync``).
 """
 import argparse
 import sys
@@ -51,7 +50,7 @@ from repro.graph.generators import make_dataset
 from repro.launch.mesh import make_halo_mesh, make_mesh
 from repro.launch.obsflags import add_obs_args, obs_session
 from repro.models.gcn import GCNConfig, gcn_forward, gcn_init
-from repro.obs import metrics as obs_metrics, trace as obs_trace
+from repro.obs import metrics as obs_metrics
 from repro.train.loop import Trainer, TrainerConfig
 from repro.train.optimizer import adamw
 
@@ -194,18 +193,12 @@ def run(args) -> None:
     assert losses[-1] < losses[0], "training must make progress"
     assert stats["hits"] >= 1 and stats["misses"] >= 1
 
-    # ---- telemetry: mirror the accounting, then trace the overlap ------------
+    # ---- telemetry: mirror the accounting ------------------------------------
     if obs_metrics.enabled():
         from repro.obs.instrument import observe_plan_cache, record_exchange
 
         record_exchange(plan, int(batch["feats"].shape[-1]))
         observe_plan_cache()
-    tracer = obs_trace.default_tracer()
-    if tracer is not None:
-        from repro.obs.instrument import overlap_timeline
-
-        print("tracing overlap: boundary collective (wire track) vs interior compute")
-        overlap_timeline(plan, batch["feats"], mesh, tracer=tracer)
 
 
 if __name__ == "__main__":

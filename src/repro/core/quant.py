@@ -49,22 +49,25 @@ def fake_quant(x: jax.Array, bits: int, percentile: float | None = None) -> jax.
     if bits >= 32 or bits <= 0:
         return x
     qmax = float(2 ** (bits - 1) - 1)
-    mag = jnp.abs(x)
-    if percentile is None:
-        amax = jnp.max(mag)
-    else:
-        # k-th largest magnitude via top_k (cheaper than a full sort; the
-        # calibration statistic carries no gradient, per standard QAT).
-        # Nearest-rank percentile: the p-th percentile of n magnitudes is the
-        # ceil(p·n/100)-th smallest, i.e. the (n − ceil(p·n/100) + 1)-th
-        # largest. The old `int(n·(1−p/100))` floored to 0 for any tensor
-        # with fewer than 1/(1−p/100) elements, so k=1 == pure amax and a
-        # single outlier silently owned the whole calibration range.
-        flat = jax.lax.stop_gradient(mag).reshape(-1)
-        n = int(flat.shape[0])
-        k = min(n, max(1, n - math.ceil(percentile / 100.0 * n) + 1))
-        amax = jax.lax.top_k(flat, k)[0][-1]
-    scale = jax.lax.stop_gradient(jnp.where(amax > 0, amax / qmax, 1.0))
+    # The range computation is one device scope: its ops carry
+    # ``quant.calibrate`` in their profiler ``tf_op`` (docs/observability.md).
+    with jax.named_scope("quant.calibrate"):
+        mag = jnp.abs(x)
+        if percentile is None:
+            amax = jnp.max(mag)
+        else:
+            # k-th largest magnitude via top_k (cheaper than a full sort; the
+            # calibration statistic carries no gradient, per standard QAT).
+            # Nearest-rank percentile: the p-th percentile of n magnitudes is
+            # the ceil(p·n/100)-th smallest, i.e. the (n − ceil(p·n/100) + 1)-th
+            # largest. The old `int(n·(1−p/100))` floored to 0 for any tensor
+            # with fewer than 1/(1−p/100) elements, so k=1 == pure amax and a
+            # single outlier silently owned the whole calibration range.
+            flat = jax.lax.stop_gradient(mag).reshape(-1)
+            n = int(flat.shape[0])
+            k = min(n, max(1, n - math.ceil(percentile / 100.0 * n) + 1))
+            amax = jax.lax.top_k(flat, k)[0][-1]
+        scale = jax.lax.stop_gradient(jnp.where(amax > 0, amax / qmax, 1.0))
     q = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax) * scale
     # Straight-through estimator: forward q, backward identity.
     return x + jax.lax.stop_gradient(q - x)

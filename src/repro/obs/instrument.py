@@ -1,4 +1,4 @@
-"""Bridges from existing accounting paths into the obs registry/tracer.
+"""Bridges from existing accounting paths into the obs registry.
 
 Nothing here invents a number: every gauge is fed from a value an existing
 layer already computes — `repro.dist.halo.HaloPlan` wire properties,
@@ -18,7 +18,7 @@ to these helpers — they sit on the halo/serve hot paths).
 """
 from __future__ import annotations
 
-from repro.obs import metrics, trace
+from repro.obs import metrics
 
 __all__ = [
     "record_exchange",
@@ -27,7 +27,6 @@ __all__ = [
     "record_delta_report",
     "record_relocalize_report",
     "record_compact_report",
-    "overlap_timeline",
 ]
 
 
@@ -159,110 +158,3 @@ def record_compact_report(report: dict) -> None:
     metrics.set_gauge("delta.pad_occupancy", float(occ.get("frac", 1.0)))
     if "compact_ms" in report:
         metrics.observe("delta.compact_ms", float(report["compact_ms"]))
-
-
-def overlap_timeline(plan, feats, mesh, tracer=None, payload: str | None = None,
-                     steps: int = 3, via: str = "all_gather"):
-    """Record a trace that SHOWS the boundary collective hiding behind
-    interior compute — the overlapped schedule of docs/communication.md as
-    a Perfetto timeline instead of an exposed-bytes formula.
-
-    Runs the split schedule as three separately-jitted shard_map programs
-    over the relocated ``(k, n_local, d)`` feature blocks:
-
-      1. ``collect``  — the boundary collective alone
-         (`repro.dist.halo.halo_exchange` / ``hier_halo_exchange``),
-      2. ``interior`` — the wire-independent aggregation term (masked
-         weights, exactly `repro.dist.halo.split_halo_aggregate`'s
-         interior half),
-      3. ``combine``  — the boundary term + sum.
-
-    Each step dispatches (1) asynchronously, runs (2) inside a synced span
-    on the calling thread's track, THEN blocks on (1) and records it as a
-    complete event on the ``wire`` track spanning dispatch → ready. The
-    wire span therefore encloses the interior span whenever the collective
-    was still in flight while interior compute ran — which is exactly
-    JAX's async-dispatch overlap mechanism, honestly measured (span edges
-    use ``block_until_ready``; nothing is drawn that did not happen).
-    Returns the final ``(k, n_local, d)`` aggregate (bit-identical to the
-    serialized schedule, per the `split_halo_aggregate` contract)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro.dist.halo import halo_exchange, hier_halo_exchange
-    from repro.graph.ops import aggregate
-
-    if tracer is None:
-        tracer = trace.enable_tracing()
-    hier = plan.is_hierarchical
-    spec_axes = plan.axes if hier else plan.axes[0]
-    arrs = plan.device_arrays()
-    if hier:
-        send_tabs, (senders, receivers, edge_w) = arrs[:2], arrs[2:]
-    else:
-        send_tabs, (senders, receivers, edge_w) = arrs[:1], arrs[1:]
-    n_local = plan.n_local
-
-    def _smap(body, n_in):
-        return jax.jit(jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(spec_axes),) * n_in, out_specs=P(spec_axes),
-            check_vma=False,
-        ))
-
-    def collect_body(h, *tabs):
-        h = h[0]
-        if hier:
-            halo = hier_halo_exchange(h, tabs[0][0], tabs[1][0], plan.axes,
-                                      via=via, payload=payload)
-        else:
-            halo = halo_exchange(h, tabs[0][0], plan.axes[0],
-                                 via=via, payload=payload)
-        return halo[None]
-
-    def interior_body(h, s, r, w):
-        h, s, r, w = h[0], s[0], r[0], w[0]
-        w_int = jnp.where(s >= n_local, jnp.zeros((), w.dtype), w)
-        return aggregate(h, jnp.minimum(s, n_local - 1), r, n_local, w_int)[None]
-
-    def combine_body(halo, out_int, s, r, w):
-        halo, out_int, s, r, w = halo[0], out_int[0], s[0], r[0], w[0]
-        if halo.shape[0] == 0:
-            return out_int[None]
-        w_bnd = jnp.where(s >= n_local, w, jnp.zeros((), w.dtype))
-        bnd = aggregate(halo, jnp.clip(s - n_local, 0, halo.shape[0] - 1),
-                        r, n_local, w_bnd)
-        return (out_int + bnd)[None]
-
-    collect = _smap(collect_body, 1 + len(send_tabs))
-    interior = _smap(interior_body, 4)
-    combine = _smap(combine_body, 5)
-
-    # Compile all three programs outside the timed loop so the recorded
-    # steps show steady-state dispatch, not tracing/lowering time.
-    with tracer.span("overlap.compile") as h:
-        halo = collect(feats, *send_tabs)
-        out_int = interior(feats, senders, receivers, edge_w)
-        h.sync = combine(halo, out_int, senders, receivers, edge_w)
-
-    wire_tid = tracer.track_tid("wire")
-    out = None
-    for i in range(steps):
-        t0 = tracer.now_us()
-        halo = collect(feats, *send_tabs)              # async dispatch
-        with tracer.span("overlap.interior_compute", args={"step": i}) as h:
-            out_int = interior(feats, senders, receivers, edge_w)
-            h.sync = out_int
-        jax.block_until_ready(halo)
-        tracer.complete(
-            "halo.exchange.boundary_collective", t0, tracer.now_us() - t0,
-            tid=wire_tid,
-            args={"step": i, "rows_per_device": plan.halo_rows_per_device,
-                  "payload": payload or "fp32"},
-        )
-        with tracer.span("overlap.boundary_combine", args={"step": i}) as h:
-            out = combine(halo, out_int, senders, receivers, edge_w)
-            h.sync = out
-    record_exchange(plan, int(feats.shape[-1]), payload)
-    return out

@@ -1,38 +1,38 @@
-"""Span tracing with Chrome trace-event export (Perfetto-loadable).
+"""Span tracing: one span API, two sinks.
 
-A :class:`TraceRecorder` collects *complete* events (``ph == "X"``) with
-microsecond timestamps relative to the recorder's creation, plus counter
-(``"C"``), instant (``"i"``) and metadata (``"M"``) events. The export
-format is the Chrome trace-event JSON object form::
+Every :func:`span` and :func:`traced` block (and every
+:meth:`TraceRecorder.span`) enters a ``jax.profiler.TraceAnnotation`` of the
+same name. Any ``jax.profiler`` session that is running (``start_trace``,
+:func:`jax_profiler_trace`, a remote capture) records it on the host line
+of its trace, on the same clock as the device ops, so a span says which
+host phase each device op or idle gap fell in. With no session running the
+annotation records nothing.
+
+When a :class:`TraceRecorder` is installed, spans are also collected as
+Chrome trace events for export (``--trace out.json``): *complete* events
+(``ph == "X"``) with microsecond timestamps relative to the recorder's
+creation, plus counter (``"C"``), instant (``"i"``) and metadata (``"M"``)
+events, in the object form::
 
     {"traceEvents": [...], "displayTimeUnit": "ms"}
 
 which chrome://tracing and https://ui.perfetto.dev load directly.
 
-Two honesty mechanisms for JAX's async dispatch:
-
-* **Sync points at span edges** — ``span(..., sync=x)`` (or setting
-  ``handle.sync`` inside the block) calls ``jax.block_until_ready`` before
-  recording the span end, so a span around a jitted call measures device
-  work, not just Python dispatch time. Off by default: un-synced spans
-  measure dispatch, which is exactly what the overlap timeline wants for
-  the interior-compute track.
-* **Raw complete events** — :meth:`TraceRecorder.complete` records a span
-  from explicit start/duration, used by `repro.obs.instrument`'s
-  ``overlap_timeline`` to place the boundary collective on its own
-  ``wire`` track spanning dispatch → ready, visibly overlapping the
-  interior-compute spans on the main track.
+JAX dispatches asynchronously, so a span around a jitted call ends when the
+call returns, not when the device is done. ``span(..., sync=x)`` (or setting
+``handle.sync`` inside the block) calls ``jax.block_until_ready`` before the
+span ends. What the device did, and whether a collective overlapped compute,
+is read from the device trace of a profiler session, not from host spans.
 
 Thread-safe: the serve engine's async path and shard_map callbacks may
 record concurrently. Each OS thread gets a small stable ``tid`` plus a
-``thread_name`` metadata event; logical tracks (e.g. ``wire``) get their
-own tids the same way. Span names follow ``layer.operation`` —
-see docs/observability.md for the catalog.
+``thread_name`` metadata event; logical tracks get their own tids the same
+way. Span names follow ``layer.operation`` — see docs/observability.md for
+the catalog and the metric that reads each span.
 
-When tracing is disabled the module-level helpers are no-ops on the same
-fast-path contract as `repro.obs.metrics`. A passthrough to
-``jax.profiler.trace`` (:func:`jax_profiler_trace`) is provided for
-when a full XLA-level profile is wanted instead of span tracing.
+With no recorder installed the module-level helpers keep the fast-path
+contract of `repro.obs.metrics`: :func:`span` returns one reused null span,
+which only enters and leaves the profiler annotation.
 """
 from __future__ import annotations
 
@@ -77,6 +77,15 @@ def _block(x) -> None:
     import jax
 
     jax.block_until_ready(x)
+
+
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    ``import repro.obs`` does not import jax."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
 
 
 class TraceRecorder:
@@ -151,23 +160,25 @@ class TraceRecorder:
     @contextlib.contextmanager
     def span(self, name: str, sync=None, args: dict | None = None,
              track: str | None = None):
-        """Context manager recording one complete event around the block.
+        """Context manager recording one complete event around the block,
+        inside a profiler annotation of the same name.
 
         ``sync`` (or ``handle.sync`` set inside) is passed to
         ``jax.block_until_ready`` before the end timestamp, attributing
         device time to the span. ``track`` places the span on a named
         logical track instead of the calling thread's row."""
         handle = SpanHandle(sync=sync, args=args)
-        t_start = self.now_us()
-        try:
-            yield handle
-        finally:
-            if handle.sync is not None:
-                _block(handle.sync)
-            t_end = self.now_us()
-            tid = self.track_tid(track) if track else self._thread_tid()
-            self.complete(name, t_start, t_end - t_start, tid=tid,
-                          args=handle.args or None)
+        with _annotation()(name):
+            t_start = self.now_us()
+            try:
+                yield handle
+            finally:
+                if handle.sync is not None:
+                    _block(handle.sync)
+                t_end = self.now_us()
+                tid = self.track_tid(track) if track else self._thread_tid()
+                self.complete(name, t_start, t_end - t_start, tid=tid,
+                              args=handle.args or None)
 
     def traced(self, name: str | None = None, sync_result: bool = False):
         """Decorator form of :meth:`span`. ``sync_result=True`` blocks on
@@ -236,21 +247,43 @@ def disable_tracing() -> None:
     _DEFAULT = None
 
 
+class _OpenAnnotations(threading.local):
+    """Per thread: the name the null span opens next, and the profiler
+    annotations it holds open, innermost last."""
+
+    def __init__(self):
+        self.name = ""
+        self.stack = []
+
+
 class _NullSpan:
-    """Disabled-path context manager: no recorder, no event, near-zero cost.
+    """Disabled-path context manager: no recorder, no Chrome event; only the
+    profiler annotation, which records nothing unless a profiler runs.
 
-    A single module-level instance is reused; the handle it yields still
-    accepts ``.sync``/``.args`` writes (they go nowhere)."""
+    A single module-level instance is reused: :func:`span` sets the name it
+    opens next on the calling thread, and each ``__enter__`` pushes an
+    annotation that the matching ``__exit__`` pops, so nested blocks close
+    in order. The handle it yields still accepts ``.sync``/``.args`` writes
+    (they go nowhere)."""
 
-    __slots__ = ("_handle",)
+    __slots__ = ("_handle", "_open")
 
     def __init__(self):
         self._handle = SpanHandle()
+        self._open = _OpenAnnotations()
+
+    def named(self, name: str) -> "_NullSpan":
+        self._open.name = name
+        return self
 
     def __enter__(self):
+        ann = _annotation()(self._open.name)
+        ann.__enter__()
+        self._open.stack.append(ann)
         return self._handle
 
     def __exit__(self, *exc):
+        self._open.stack.pop().__exit__(*exc)
         self._handle.sync = None
         return False
 
@@ -260,7 +293,7 @@ _NULL_SPAN = _NullSpan()
 
 def span(name: str, sync=None, args: dict | None = None, track: str | None = None):
     if _DEFAULT is None:
-        return _NULL_SPAN
+        return _NULL_SPAN.named(name)
     return _DEFAULT.span(name, sync=sync, args=args, track=track)
 
 
@@ -274,7 +307,8 @@ def traced(name: str | None = None, sync_result: bool = False):
         def wrapper(*a, **kw):
             tr = _DEFAULT
             if tr is None:
-                return fn(*a, **kw)
+                with _annotation()(label):
+                    return fn(*a, **kw)
             with tr.span(label) as h:
                 out = fn(*a, **kw)
                 if sync_result:
@@ -308,10 +342,11 @@ def export(path: str) -> bool:
 def jax_profiler_trace(log_dir: str):
     """Passthrough to ``jax.profiler.trace`` for full XLA-level profiles.
 
-    Span tracing answers "does the collective overlap the interior
-    compute"; the jax profiler answers "what is XLA doing inside that
-    span". A profiler that cannot start raises: a run asked for a device
-    trace must not finish without one."""
+    The profile holds the device ops (with their named scopes in
+    ``tf_op``) and every span entered meanwhile, on one clock: it answers
+    "what did the device do inside that span" and "did the collective
+    overlap compute". A profiler that cannot start raises: a run asked for
+    a device trace must not finish without one."""
     import jax.profiler as _prof
 
     with _prof.trace(log_dir):

@@ -130,10 +130,12 @@ class Trainer:
                 break
             t0 = time.perf_counter()
             with _obs_trace.span("train.step", args={"step": self.step}):
-                self.params, self.opt_state, self.residual, loss = self._step_fn(
-                    self.params, self.opt_state, self.residual, batch
-                )
-                loss = float(loss)      # blocks: the span covers device work
+                with _obs_trace.span("train.dispatch"):
+                    self.params, self.opt_state, self.residual, loss = self._step_fn(
+                        self.params, self.opt_state, self.residual, batch
+                    )
+                with _obs_trace.span("train.sync"):
+                    loss = float(loss)  # blocks: the span covers device work
             dt = time.perf_counter() - t0
             self.step += 1
             losses.append(loss)

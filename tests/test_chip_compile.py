@@ -110,6 +110,36 @@ def test_fused_gcn_layer_compiles(one_chip, order, f_in, dtype):
     assert "tpu_custom_call" in text
 
 
+def _compile_bsr_train_step(cfg, n, e, tables, sharding):
+    """The jitted train step of ``coin_gcn`` on the bsr backend, compiled for
+    an ``n``-node, ``e``-edge graph (self-loops included) with ``tables``
+    (vals, cols, lens) as its blocked adjacency."""
+    from repro.dist.policy import NO_POLICY
+    from repro.launch.steps import gnn_loss_fn
+    from repro.models.gcn import gcn_init
+    from repro.train.loop import Trainer
+    from repro.train.optimizer import adamw
+
+    tr = Trainer(gnn_loss_fn("coin_gcn", cfg, NO_POLICY), adamw(1e-3),
+                 gcn_init(jax.random.PRNGKey(0), cfg))
+    vals, cols, lens = tables
+    batch = {
+        "feats": _sds((n, cfg.layer_dims[0]), jnp.float32, sharding),
+        "senders": _sds((e,), jnp.int32, sharding),
+        "receivers": _sds((e,), jnp.int32, sharding),
+        "edge_weight": _sds((e,), jnp.float32, sharding),
+        "labels": _sds((n,), jnp.int32, sharding),
+        "label_mask": _sds((n,), jnp.float32, sharding),
+        "bsr_vals": vals, "bsr_cols": cols, "bsr_lens": lens,
+    }
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype, sharding), tree)
+
+    return tr._step_fn.lower(
+        abstract(tr.params), abstract(tr.opt_state), None, batch).compile()
+
+
 def test_coin_gcn_bsr_train_step_compiles_at_pubmed_width(one_chip, native):
     from repro.dist.policy import NO_POLICY
     from repro.launch.steps import gnn_loss_fn
@@ -119,25 +149,8 @@ def test_coin_gcn_bsr_train_step_compiles_at_pubmed_width(one_chip, native):
 
     spec = _fp32_spec()
     cfg = dataclasses.replace(spec.make_config(spec.shapes["pubmed"]), backend="bsr")
-    tr = Trainer(gnn_loss_fn("coin_gcn", cfg, NO_POLICY), adamw(1e-3),
-                 gcn_init(jax.random.PRNGKey(0), cfg))
-    n, e = N_PUBMED, E_PUBMED + N_PUBMED          # + self-loops
-    vals, cols, lens = _tables(one_chip, jnp.float32)
-    batch = {
-        "feats": _sds((n, cfg.layer_dims[0]), jnp.float32, one_chip),
-        "senders": _sds((e,), jnp.int32, one_chip),
-        "receivers": _sds((e,), jnp.int32, one_chip),
-        "edge_weight": _sds((e,), jnp.float32, one_chip),
-        "labels": _sds((n,), jnp.int32, one_chip),
-        "label_mask": _sds((n,), jnp.float32, one_chip),
-        "bsr_vals": vals, "bsr_cols": cols, "bsr_lens": lens,
-    }
-
-    def abstract(tree):
-        return jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
-
-    compiled = tr._step_fn.lower(
-        abstract(tr.params), abstract(tr.opt_state), None, batch).compile()
+    compiled = _compile_bsr_train_step(
+        cfg, N_PUBMED, E_PUBMED + N_PUBMED, _tables(one_chip, jnp.float32), one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes < 16e9
 
@@ -152,3 +165,28 @@ def test_flat_halo_train_step_compiles_on_four_chips(topo, native):
     assert cell.comm == "halo" and cell.halo_plan.k == 4 and cell.bsr_stats is not None
     text = cell.lower(mesh).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_qat_bsr_train_step_keeps_kernel_names_and_names_its_calibration(one_chip, native):
+    """The ``quant.calibrate`` scope reaches the compiled calibration sorts
+    and leaves every instruction name alone: the two forward fused kernels
+    are still ``jvp_jit_fused_gcn_layer_pallas__``, the name the chip
+    trace's kernel metrics match. A small graph, with the published 4-bit
+    QAT on."""
+    import re
+
+    from repro.core.quant import QuantConfig
+    from repro.models.gcn import GCNConfig
+
+    r, t = 4, 3
+    cfg = GCNConfig(layer_dims=(256, 16, 3), backend="bsr",
+                    quant=QuantConfig(weight_bits=4, act_bits=4, act_percentile=99.9))
+    tables = (_sds((r, t, B, B), jnp.float32, one_chip), _sds((r, t), jnp.int32, one_chip),
+              _sds((r,), jnp.int32, one_chip))
+    text = _compile_bsr_train_step(cfg, r * B - 20, 3000, tables, one_chip).as_text()
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*tpu_custom_call", text, re.M)
+    assert len(kernels) == 2
+    assert all(re.fullmatch(r"jvp_jit_fused_gcn_layer_pallas__(\.\d+)?", k) for k in kernels)
+    sorts = [line for line in text.splitlines() if re.search(r" sort\(", line) and " = " in line]
+    assert len(sorts) == 2
+    assert all('jvp(quant.calibrate)/top_k"' in line for line in sorts)

@@ -11,10 +11,9 @@ contract: the registry snapshot must reproduce, bit-for-bit,
 * the `DeltaPlanner.apply` report (repair latency, drift gauge).
 
 The slow test drives the 8-device distributed example end to end with
-``--trace``/``--metrics`` and asserts the exported Chrome trace shows the
-boundary-collective wire span enclosing an interior-compute span — the
-overlap, demonstrated from the artifact a user would actually load into
-Perfetto.
+``--trace``/``--metrics``: the snapshot reproduces the plan's wire-byte
+accounting, and the exported Chrome trace holds every training step's
+spans.
 """
 import json
 import os
@@ -198,14 +197,13 @@ def test_delta_report_gauges_and_drift():
     assert metrics.snapshot()["delta.applies"]["value"] == 2.0
 
 
-# ------------------------------------------- 8-device traced overlap (slow)
+# ------------------------------------------- 8-device traced example (slow)
 @pytest.mark.slow
-def test_traced_example_shows_overlap_subprocess(tmp_path):
+def test_traced_example_exports_metrics_subprocess(tmp_path):
     """Drive the distributed example with --trace/--metrics on 8 host
-    devices; the exported Chrome trace must contain the boundary-collective
-    span on the wire track ENCLOSING an interior-compute span (the async
-    dispatch overlap), and the metrics snapshot must reproduce the plan's
-    wire-byte accounting."""
+    devices; the metrics snapshot must reproduce the plan's wire-byte
+    accounting, and the exported Chrome trace must hold each step's
+    ``train.step`` span enclosing its ``train.dispatch`` and ``train.sync``."""
     trace_path = tmp_path / "trace.json"
     metrics_path = tmp_path / "metrics.json"
     env = dict(os.environ,
@@ -218,20 +216,14 @@ def test_traced_example_shows_overlap_subprocess(tmp_path):
         cwd=os.path.join(os.path.dirname(__file__), ".."), env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]
-    doc = json.loads(trace_path.read_text())
-    ev = doc["traceEvents"]
-    wire = [e for e in ev if e.get("name") == "halo.exchange.boundary_collective"]
-    interior = [e for e in ev if e.get("name") == "overlap.interior_compute"]
-    assert wire and interior
-    assert any(
-        w["ts"] <= i["ts"] and i["ts"] + i["dur"] <= w["ts"] + w["dur"]
-        for w in wire for i in interior
-    ), "no wire span encloses an interior-compute span"
-    # wire spans live on their own named track
-    tids = {e["tid"] for e in wire}
-    tracks = {e["tid"]: e["args"]["name"] for e in ev
-              if e["ph"] == "M" and e["name"] == "thread_name"}
-    assert all(tracks.get(t) == "wire" for t in tids)
+    ev = json.loads(trace_path.read_text())["traceEvents"]
+    spans = {name: [e for e in ev if e.get("name") == name]
+             for name in ("train.step", "train.dispatch", "train.sync")}
+    assert all(len(v) == 12 for v in spans.values())
+    for step, d, s in zip(*spans.values()):
+        for inner in (d, s):
+            assert step["ts"] <= inner["ts"]
+            assert inner["ts"] + inner["dur"] <= step["ts"] + step["dur"]
     snap = json.loads(metrics_path.read_text())
     rows = snap["halo.rows_per_device{tier=total}"]["value"]
     d_feat = 64  # reduced cora feature width (make_dataset("cora", reduced=True))
