@@ -12,11 +12,14 @@ imports this file. Keep these tests in this one file for the same reason.
 The compiles keep the persistent compile cache off — an entry written for a
 described chip cannot be read back without one.
 
-The train steps compile with quantization off: the published config's
-4-bit QAT adds a `lax.top_k` calibration over every input activation, which
-takes the host compiler about 45 s at pubmed width; `chip_smoke.py` runs it.
+The train steps at real widths compile with quantization off, which keeps
+them to the layers' own path. The published config's 4-bit QAT calibration
+is compiled by the last two tests: a small QAT step, and the selection at
+nell's width.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -168,13 +171,12 @@ def test_flat_halo_train_step_compiles_on_four_chips(topo, native):
 
 
 def test_qat_bsr_train_step_keeps_kernel_names_and_names_its_calibration(one_chip, native):
-    """The ``quant.calibrate`` scope reaches the compiled calibration sorts
-    and leaves every instruction name alone: the two forward fused kernels
-    are still ``jvp_jit_fused_gcn_layer_pallas__``, the name the chip
-    trace's kernel metrics match. A small graph, with the published 4-bit
-    QAT on."""
-    import re
-
+    """The compiled QAT step calibrates without a sort, the ``quant.calibrate``
+    scope reaches every counting pass of the selection, and every
+    instruction name is left alone: the two forward fused kernels are still
+    ``jvp_jit_fused_gcn_layer_pallas__``, the name the chip trace's kernel
+    metrics match. A small graph, with the published 4-bit QAT on."""
+    from repro.core import quant
     from repro.core.quant import QuantConfig
     from repro.models.gcn import GCNConfig
 
@@ -187,6 +189,21 @@ def test_qat_bsr_train_step_keeps_kernel_names_and_names_its_calibration(one_chi
     kernels = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*tpu_custom_call", text, re.M)
     assert len(kernels) == 2
     assert all(re.fullmatch(r"jvp_jit_fused_gcn_layer_pallas__(\.\d+)?", k) for k in kernels)
-    sorts = [line for line in text.splitlines() if re.search(r" sort\(", line) and " = " in line]
-    assert len(sorts) == 2
-    assert all('jvp(quant.calibrate)/top_k"' in line for line in sorts)
+    assert not re.search(r" sort\(", text)
+    # One fused read per pass, for each of the two percentile calibrations.
+    passes = [line for line in text.splitlines()
+              if re.match(r"^\s*(?:ROOT )?%[\w.\-]+ = .* fusion\(", line)
+              and re.search(r'op_name="[^"]*/reduce"', line)]
+    assert len(passes) == 2 * math.ceil(31 / quant._DIGIT_BITS)
+    assert all('op_name="jit(step)/jvp(quant.calibrate)/reduce"' in line for line in passes)
+
+
+def test_percentile_calibration_at_nell_width_lowers_without_a_sort():
+    """The selection at nell's f32[65755, 5414] layer-0 input: no ``top_k``,
+    which XLA lowers to a full sort of every magnitude. Lowering only."""
+    from repro.core.quant import fake_quant
+
+    x = jax.ShapeDtypeStruct((65755, 5414), jnp.float32)
+    lowered = jax.jit(lambda x: fake_quant(x, 4, percentile=99.9)).lower(x)
+    assert "quant.calibrate" in lowered.as_text(debug_info=True)
+    assert not re.search(r"sort|top_?k", lowered.as_text(), re.I)

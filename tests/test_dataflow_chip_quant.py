@@ -1,9 +1,13 @@
 """Dataflow reordering (§IV-C3), chip capacity (§V-C), quantization (§V-B)."""
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from hypothesis.extra import numpy as hnp
 
 from repro.core.chip import ChipModel, chips_required
 from repro.core.dataflow import (
@@ -16,6 +20,7 @@ from repro.core.quant import (
     QuantConfig,
     dequantize_payload,
     fake_quant,
+    kth_largest,
     payload_bits,
     quantize_payload,
     quantize_tree,
@@ -86,9 +91,11 @@ def test_fake_quant_level_count_and_ste():
     for bits in [2, 3, 4, 8]:
         q = fake_quant(x, bits)
         assert len(np.unique(np.asarray(q))) <= 2**bits
-    # straight-through: gradient of sum(fake_quant(x)) is all-ones
-    g = jax.grad(lambda x: fake_quant(x, 4).sum())(x)
-    assert np.allclose(np.asarray(g), 1.0)
+    # straight-through: gradient of sum(fake_quant(x)) is all-ones, with a
+    # percentile scale too
+    for p in (None, 99.0, 99.9):
+        g = jax.grad(lambda x: fake_quant(x, 4, percentile=p).sum())(x)
+        np.testing.assert_array_equal(np.asarray(g), np.ones(x.shape, np.float32))
     # ≥32 bits is a no-op
     assert np.array_equal(np.asarray(fake_quant(x, 32)), np.asarray(x))
 
@@ -148,6 +155,100 @@ def test_quantize_tree_threads_percentile():
     assert out["n"] == 3
     out_amax = quantize_tree(tree, 4)
     assert not np.array_equal(np.asarray(out_amax["a"]), ref)
+
+
+def _rank_k(n, p):
+    return min(n, max(1, n - math.ceil(p / 100.0 * n) + 1))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _fake_quant_top_k(x, bits, p):
+    """`fake_quant` with the percentile scale taken from `lax.top_k`."""
+    qmax = float(2 ** (bits - 1) - 1)
+    mag = jnp.abs(x)
+    amax = jax.lax.top_k(mag.reshape(-1), _rank_k(mag.size, p))[0][-1]
+    scale = jnp.where(amax > 0, amax / qmax, 1.0)
+    q = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax) * scale
+    return x + jax.lax.stop_gradient(q - x)
+
+
+# Both sides jitted: XLA may rewrite ``x / scale`` in either.
+_kth_largest = jax.jit(kth_largest, static_argnums=1)
+_fake_quant = jax.jit(fake_quant, static_argnums=(1, 2))
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.int32)
+
+
+def _assert_selection_exact(x, p):
+    """The selection's scale and `fake_quant`'s whole output, bit for bit
+    against `lax.top_k` and a nearest rank of `np.sort`."""
+    x = np.asarray(x, np.float32)
+    n = x.size
+    mag = jnp.abs(jnp.asarray(x))
+    amax = _kth_largest(mag, _rank_k(n, p))
+    top_k = jax.lax.top_k(mag.reshape(-1), _rank_k(n, p))[0][-1]
+    nearest = np.sort(np.abs(x).ravel())[math.ceil(p / 100.0 * n) - 1]
+    assert amax.dtype == jnp.float32
+    assert _bits(amax) == _bits(top_k) == _bits(nearest), (float(amax), float(top_k), nearest)
+    out = _fake_quant(jnp.asarray(x), 4, p)
+    np.testing.assert_array_equal(_bits(out), _bits(_fake_quant_top_k(jnp.asarray(x), 4, p)))
+
+
+def _case(name, rng):
+    if name == "n1":
+        return np.array([-2.5], np.float32)
+    if name == "k1":                                # n < 1/(1 − p/100): the rank is the max
+        return np.concatenate([np.linspace(-1, 1, 49), [20.0]]).astype(np.float32)
+    if name == "all_zero":                          # amax 0: the scale falls back to 1
+        return np.zeros((40, 25), np.float32)
+    if name == "zeros_99":                          # like nell's 32 nonzeros in 5414
+        x = rng.standard_normal((120, 90)).astype(np.float32)
+        return np.where(rng.random(x.shape) < 0.99, 0.0, x).astype(np.float32)
+    if name == "ties":
+        return (np.round(rng.standard_normal((64, 37)) * 2) / 2).astype(np.float32)
+    if name == "denormal":
+        x = (rng.standard_normal(3000) * 1e-39).astype(np.float32)
+        assert np.any((x != 0) & (np.abs(x) < np.finfo(np.float32).tiny))
+        return x
+    if name == "signed_zero":
+        x = np.where(rng.random(2000) < 0.5, 0.0, -0.0).astype(np.float32)
+        x[:3] = [1.5, -0.25, 3.0]
+        return x
+    if name == "inf":
+        x = rng.standard_normal((50, 60)).astype(np.float32)
+        x.flat[rng.choice(x.size, 4, replace=False)] = [np.inf, -np.inf, np.inf, -np.inf]
+        return x
+    if name == "inf_owns_rank":                     # the k-th largest is +inf itself
+        return np.array([np.inf, -np.inf, 1.0, -3.0], np.float32)
+    if name == "two_d":
+        return (rng.standard_normal((300, 17)) * rng.lognormal(size=(300, 1))).astype(np.float32)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("p", [99.0, 99.9])
+@pytest.mark.parametrize("name", ["n1", "k1", "all_zero", "zeros_99", "ties", "denormal",
+                                  "signed_zero", "inf", "inf_owns_rank", "two_d"])
+def test_kth_largest_matches_top_k_and_sort_bit_for_bit(name, p):
+    _assert_selection_exact(_case(name, np.random.default_rng(7)), p)
+
+
+@settings(max_examples=40, deadline=None)
+@given(
+    x=hnp.arrays(np.float32, st.sampled_from([(1,), (9,), (150,), (1200,), (40, 35)]),
+                 elements=st.floats(width=32, allow_nan=False)),
+    p=st.sampled_from([99.0, 99.9]),
+)
+def test_kth_largest_matches_top_k_and_sort_on_any_floats(x, p):
+    _assert_selection_exact(x, p)
+
+
+def test_kth_largest_keeps_a_narrower_float():
+    mag = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (70, 30), jnp.bfloat16))
+    got = _kth_largest(mag, 5)
+    assert got.dtype == jnp.bfloat16
+    assert got == jax.lax.top_k(mag.reshape(-1), 5)[0][-1]
 
 
 # --------------------------------------------------------- halo wire payloads

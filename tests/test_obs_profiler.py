@@ -9,6 +9,7 @@
   HLO ``op_name`` (the profiler's ``tf_op``) in the compiled train step.
 """
 import itertools
+import math
 import pathlib
 import re
 
@@ -144,11 +145,18 @@ def _tiny_coin_gcn(backend: str):
 
 @pytest.mark.parametrize("backend", ["segment", "bsr"])
 def test_compiled_calibration_sort_carries_the_scope(backend):
+    """The calibration sorts no more; each counting pass of its selection
+    (one variadic ``reduce``) carries the scope and keeps its name."""
+    from repro.core import quant
+
     tr, batch = _tiny_coin_gcn(backend)
     text = tr._step_fn.lower(tr.params, tr.opt_state, None, batch).compile().as_text()
-    sorts = [line for line in text.splitlines() if re.search(r"= \S+ sort\(", line)]
-    assert len(sorts) == 2                          # one top_k per layer's activations
-    for line in sorts:
+    assert not re.search(r"= \S+ sort\(", text)
+    passes = [line for line in text.splitlines()                  # lax.reduce's own name
+              if re.match(r"\s*(?:ROOT )?%\S+ = .*? reduce\(", line)
+              and re.search(r'op_name="[^"]*/reduce"', line)]
+    assert len(passes) == 2 * math.ceil(31 / quant._DIGIT_BITS)   # per layer's activations
+    for line in passes:
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         assert re.split(r"[/()]", op_name).count("quant.calibrate") == 1, op_name
-        assert re.match(r"\s*%sort(\.\d+)? = ", line)  # the instruction keeps its name
+        assert re.match(r"\s*(?:ROOT )?%reduce(\.\d+)? = ", line)  # the instruction keeps its name
