@@ -10,7 +10,8 @@ Step kinds per family:
   lm/prefill    — last-position logits               (serve_step)
   lm/decode     — one token against the KV cache     (serve_step)
   gnn/graph     — regression loss + grads + AdamW    (train_step; sampled
-                  cells vmap a block per data shard)
+                  cells vmap a block per data shard, graphcast one
+                  example per data shard)
   recsys/train  — BCE loss + grads + AdamW
   recsys/serve  — batched logits
   recsys/retrieval — 1×N candidate scoring
@@ -267,7 +268,9 @@ def _bsr_tables(batch: dict, prefix: str = "bsr_"):
 def gnn_loss_fn(arch_id: str, cfg, policy: ShardingPolicy, n_loss_nodes: int | None = None):
     """``loss(params, batch)`` of a GNN arch on one device: cross-entropy
     for coin_gcn (``backend="bsr"`` reads its blocked adjacency from the
-    batch's ``bsr_vals``/``bsr_cols``/``bsr_lens``), regression elsewhere
+    batch's ``bsr_vals``/``bsr_cols``/``bsr_lens``), GraphCast's weighted
+    MSE on one example for graphcast (its batch: `repro.models.graphcast`;
+    ``policy`` unused, the model is not sharded), regression elsewhere
     (sliced to the first ``n_loss_nodes`` rows for sampled blocks — losses
     are computed on the seed nodes only)."""
 
@@ -286,14 +289,10 @@ def gnn_loss_fn(arch_id: str, cfg, policy: ShardingPolicy, n_loss_nodes: int | N
             )
             return _mse(pred, batch["target"])
     elif arch_id == "graphcast":
-        from repro.models.graphcast import graphcast_forward
+        from repro.models.graphcast import graphcast_loss
 
         def loss(params, batch):
-            pred = graphcast_forward(
-                params, batch["feats"], batch["edge_feats"], batch["senders"],
-                batch["receivers"], cfg, policy,
-            )
-            return _mse(pred, batch["target"])
+            return graphcast_loss(params, batch, cfg)
     elif arch_id == "equiformer-v2":
         from repro.models.equiformer_v2 import equiformer_forward
 
@@ -334,7 +333,7 @@ def _gnn_params(arch_id: str, cfg, dtype):
     if arch_id == "graphcast":
         from repro.models.graphcast import graphcast_init
 
-        return jax.eval_shape(lambda k: graphcast_init(k, cfg, dtype), key)
+        return jax.eval_shape(lambda k: graphcast_init(k, cfg), key)     # float32 only
     if arch_id == "equiformer-v2":
         from repro.models.equiformer_v2 import equiformer_init
 
@@ -370,6 +369,25 @@ def _gnn_sizes(shape: ShapeSpec, pad_mult: int) -> tuple[int, int]:
     return _pad_to(n, pad_mult), _pad_to(e, pad_mult)
 
 
+def _graphcast_batch_abstract(cfg, lead: tuple = ()) -> dict:
+    """Abstract GraphCast batch (`repro.models.graphcast`), sized by the
+    geometry `repro.graph.sphere` builds for ``cfg``."""
+    from repro.graph.sphere import graph_sizes
+
+    z = dict(graph_sizes(*cfg.geometry))
+    batch = {
+        "grid_inputs": _sds(lead + (z["n_grid"], cfg.d_grid_in), F32),
+        "grid_target": _sds(lead + (z["n_grid"], cfg.n_vars), F32),
+        "grid_nodes": _sds(lead + (z["n_grid"], 3), F32),
+        "mesh_nodes": _sds(lead + (z["n_mesh"], 3), F32),
+    }
+    for name, n_edges in (("mesh", z["n_mesh_edges"]), ("g2m", z["n_g2m"]), ("m2g", z["n_m2g"])):
+        batch[f"{name}_senders"] = _sds(lead + (n_edges,), I32)
+        batch[f"{name}_receivers"] = _sds(lead + (n_edges,), I32)
+        batch[f"{name}_edges"] = _sds(lead + (n_edges, 4), F32)
+    return batch
+
+
 def _gnn_batch_abstract(arch_id: str, shape: ShapeSpec, cfg, n_blocks: int | None, pad_mult: int):
     """Abstract batch dict. n_blocks=None → single global graph; else a
     leading block axis (one sampled block per data shard)."""
@@ -382,14 +400,12 @@ def _gnn_batch_abstract(arch_id: str, shape: ShapeSpec, cfg, n_blocks: int | Non
     }
     if arch_id in ("egnn", "equiformer-v2"):
         batch["pos"] = _sds(lead + (n, 3), F32)
-    if arch_id == "graphcast":
-        batch["edge_feats"] = _sds(lead + (e, cfg.d_edge_in), F32)
     if arch_id == "coin_gcn":
         batch["edge_weight"] = _sds(lead + (e,), F32)
         batch["labels"] = _sds(lead + (n,), I32)
         batch["label_mask"] = _sds(lead + (n,), F32)
     else:
-        n_out = cfg.n_vars if arch_id == "graphcast" else getattr(cfg, "d_out", 1)
+        n_out = getattr(cfg, "d_out", 1)
         n_tgt = shape.batch_nodes if n_blocks is not None else n
         batch["target"] = _sds(lead + (n_tgt, n_out), F32)
     return batch
@@ -402,6 +418,10 @@ def _gnn_flops(arch_id: str, shape: ShapeSpec, cfg, bsr_stats: dict | None = Non
     the coin_gcn aggregation term to the blocked cost model so hillclimb and
     the dry-run see the kernel's real nnz_blocks·B²·F work.
     """
+    if arch_id == "graphcast":
+        from repro.models.graphcast import forward_flops
+
+        return forward_flops(cfg)
     n, e = float(shape.n_nodes), float(shape.n_edges)
     L = cfg.n_layers
     if arch_id == "equiformer-v2":
@@ -418,11 +438,6 @@ def _gnn_flops(arch_id: str, shape: ShapeSpec, cfg, bsr_stats: dict | None = Non
         d = cfg.d_hidden
         per_e = (2 * d + 1) * d + d * d + (d * d + d)                  # φ_e (2-layer) + φ_x
         per_n = 2 * d * d + d * d                                      # φ_h
-        return 2.0 * L * (e * per_e + n * per_n)
-    if arch_id == "graphcast":
-        d = cfg.d_hidden
-        per_e = 3 * d * d + d * d
-        per_n = 2 * d * d + d * d
         return 2.0 * L * (e * per_e + n * per_n)
     if arch_id == "pna":
         d = cfg.d_hidden
@@ -533,13 +548,6 @@ def _gnn_halo_device_loss(arch_id: str, cfg):
                 params, b["feats"], b["pos"], b["senders"], b["receivers"], cfg, pol,
                 edge_mask=edge_mask,
             )
-        elif arch_id == "graphcast":
-            from repro.models.graphcast import graphcast_forward
-
-            pred = graphcast_forward(
-                params, b["feats"], b["edge_feats"], b["senders"], b["receivers"], cfg, pol,
-                edge_mask=edge_mask,
-            )
         elif arch_id == "equiformer-v2":
             from repro.models.equiformer_v2 import equiformer_forward
 
@@ -626,7 +634,7 @@ def _gnn_halo_batch_abstract(
     halo-only columns — the overlapped schedule's pair) so no tile is ever
     materialized for abstract cells. A legacy single-table record (no
     "interior" key, `plan_blocked_shape`) sizes just the combined triple."""
-    k, n_local, e_local = plan.k, plan.n_local, plan.e_local
+    k, n_local = plan.k, plan.n_local
     if plan.is_hierarchical:
         sloc, srem, sl, rl, ew = plan.abstract_inputs()
         send = {"send_loc": sloc, "send_rem": srem}
@@ -642,8 +650,6 @@ def _gnn_halo_batch_abstract(
     }
     if arch_id in ("egnn", "equiformer-v2"):
         batch["pos"] = _sds((k, n_local, 3), F32)
-    if arch_id == "graphcast":
-        batch["edge_feats"] = _sds((k, e_local, cfg.d_edge_in), F32)
     if arch_id == "coin_gcn":
         if bsr_stats is not None:
             if "interior" in bsr_stats:
@@ -659,8 +665,7 @@ def _gnn_halo_batch_abstract(
         batch["labels"] = _sds((k, n_local), I32)
         batch["label_mask"] = _sds((k, n_local), F32)
     else:
-        n_out = cfg.n_vars if arch_id == "graphcast" else getattr(cfg, "d_out", 1)
-        batch["target"] = _sds((k, n_local, n_out), F32)
+        batch["target"] = _sds((k, n_local, getattr(cfg, "d_out", 1)), F32)
         batch["node_mask"] = _sds((k, n_local), F32)
     return batch
 
@@ -769,6 +774,42 @@ def _gnn_halo_cell(
     )
 
 
+def _graphcast_cell(spec: ArchSpec, shape: ShapeSpec, mesh, cfg) -> Cell:
+    """GraphCast trains data-parallel: one example per data shard (its own
+    copy of the graphs), weights replicated, the loss the shards' mean."""
+    da = data_axes(mesh)
+    n_data = int(np.prod([mesh.shape[a] for a in da]))
+    params_abs = _gnn_params(spec.arch_id, cfg, F32)
+    p_specs = sh.replicated_specs(params_abs)
+    p_shard = sh.tree_named(mesh, p_specs)
+    batch_abs = _graphcast_batch_abstract(cfg, (n_data,))
+    batch_spec = jax.tree_util.tree_map(
+        lambda l: sh.named(mesh, P(da, *([None] * (len(l.shape) - 1)))), batch_abs)
+    loss_fn = gnn_loss_fn(spec.arch_id, cfg, NO_POLICY)
+
+    def total_loss(params, batch):
+        return jnp.mean(jax.vmap(lambda b: loss_fn(params, b))(batch))
+
+    opt = adamw(lr=1e-3)
+    opt_abs = jax.eval_shape(opt.init, params_abs)
+    o_shard = sh.tree_named(mesh, _opt_specs(opt_abs, p_specs))
+
+    def train_step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(total_loss)(params, batch)
+        new_params, new_opt = opt.update(grads, opt_state, params)
+        return new_params, new_opt, loss
+
+    return Cell(
+        spec.arch_id, shape.name, "train_step",
+        train_step,
+        (params_abs, opt_abs, batch_abs),
+        (p_shard, o_shard, batch_spec),
+        (p_shard, o_shard, sh.named(mesh, P())),
+        model_flops=_gnn_flops(spec.arch_id, shape, cfg) * 3.0 * n_data,
+        note=f"data parallel, one example per shard x{n_data}",
+    )
+
+
 def _gnn_cell(
     spec: ArchSpec, shape: ShapeSpec, mesh, dtype=F32,
     _as_cost_cell: bool = False, comm: str | None = None, optimized: bool = False,
@@ -777,6 +818,8 @@ def _gnn_cell(
     import dataclasses as dc
 
     cfg = spec.make_config(shape)
+    if spec.arch_id == "graphcast":
+        return _graphcast_cell(spec, shape, mesh, cfg)
     if (
         optimized and spec.arch_id == "coin_gcn" and shape.batch_nodes is None
         and comm != "broadcast"

@@ -6,7 +6,9 @@
 
 Runs the REDUCED config on the local device(s) unless ``--shape`` names a
 registry shape of the arch: coin_gcn then trains its published config on
-that Table-I dataset at full size (`repro.graph.generators.make_dataset`).
+that Table-I dataset at full size (`repro.graph.generators.make_dataset`),
+graphcast (``--shape era5_1deg``) GraphCast_small on its 1° grid and
+multimesh, one seeded synthetic example per step.
 The full configs of the other archs are exercised by the dry-run
 (`repro.launch.dryrun`). The driver wires the complete substrate: synthetic
 data stream → jitted train step → AdamW → checkpointing → straggler monitor,
@@ -49,8 +51,8 @@ def _shape_batch(spec, shape: str):
     batch); features are cast to fp32 on the host, so the float16 that
     `make_dataset` emits for large sets never reaches the device."""
     if spec.arch_id != "coin_gcn":
-        raise SystemExit(f"--shape trains coin_gcn on a Table-I dataset; "
-                         f"{spec.arch_id} has no dataset for {shape!r}")
+        raise SystemExit(f"--shape trains coin_gcn on a Table-I dataset or graphcast on "
+                         f"its grid; {spec.arch_id} has no dataset for {shape!r}")
     cfg = spec.make_config(spec.shapes[shape])
     _, g = make_dataset(shape)
     if g.features.shape[1] != cfg.layer_dims[0]:
@@ -68,11 +70,41 @@ def _shape_batch(spec, shape: str):
     return cfg, g, batch
 
 
+def _graphcast_batches(cfg, n_examples: int = 4, seed: int = 0):
+    """GraphCast batches over ``cfg``'s graphs, cycling ``n_examples``
+    synthetic examples: inputs N(0, 1), the target the state at t plus
+    N(0, 1) (fields taken as normalized)."""
+    from repro.models.graphcast import graphcast_graph
+
+    graph = {k: jnp.asarray(v) for k, v in graphcast_graph(cfg).arrays().items()}
+    n_grid = graph["grid_nodes"].shape[0]
+    rng = np.random.default_rng(seed)
+    examples = []
+    for _ in range(n_examples):
+        x = rng.standard_normal((n_grid, cfg.d_grid_in), np.float32)
+        state = x[:, (cfg.n_input_steps - 1) * cfg.n_vars: cfg.n_input_steps * cfg.n_vars]
+        y = state + rng.standard_normal(state.shape, np.float32)
+        examples.append(dict(graph, grid_inputs=jnp.asarray(x), grid_target=jnp.asarray(y)))
+
+    def batches():
+        while True:
+            yield from examples
+
+    return batches
+
+
 def _gnn_setup(spec, relocalize_threshold: float = 0.0, shape: str | None = None):
     from repro.graph.generators import citation_like
     from repro.launch.steps import gnn_loss_fn
     from repro.dist.policy import NO_POLICY
 
+    if spec.arch_id == "graphcast":
+        if relocalize_threshold > 0:
+            raise SystemExit("--relocalize-threshold churns a generic graph; graphcast's "
+                             "graphs are fixed by its grid and mesh")
+        cfg = spec.make_reduced() if shape is None else spec.make_config(spec.shapes[shape])
+        return (_init_gnn(spec.arch_id, cfg), gnn_loss_fn(spec.arch_id, cfg, NO_POLICY),
+                _graphcast_batches(cfg))
     if shape is not None:
         cfg, g, base = _shape_batch(spec, shape)
     else:
@@ -89,17 +121,13 @@ def _gnn_setup(spec, relocalize_threshold: float = 0.0, shape: str | None = None
         }
         if spec.arch_id in ("egnn", "equiformer-v2"):
             base["pos"] = jnp.asarray(rng.standard_normal((g.n_nodes, 3)), jnp.float32)
-        if spec.arch_id == "graphcast":
-            base["edge_feats"] = jnp.asarray(
-                rng.standard_normal((g.n_edges, cfg.d_edge_in)), jnp.float32)
         if spec.arch_id == "coin_gcn":
             base["edge_weight"] = jnp.ones(g.n_edges)
             base["labels"] = jnp.asarray(g.labels)
             base["label_mask"] = jnp.ones(g.n_nodes)
         else:
-            n_out = cfg.n_vars if spec.arch_id == "graphcast" else cfg.d_out
             base["target"] = jnp.asarray(
-                rng.standard_normal((g.n_nodes, n_out)) * 0.1, jnp.float32)
+                rng.standard_normal((g.n_nodes, cfg.d_out)) * 0.1, jnp.float32)
 
     loss = gnn_loss_fn(spec.arch_id, cfg, NO_POLICY)
     params = _init_gnn(spec.arch_id, cfg)
@@ -201,7 +229,7 @@ def main(argv=None) -> list[float]:
     ap.add_argument("--arch", required=True, choices=ALL_ARCHS)
     ap.add_argument("--shape", default=None,
                     help="registry shape of the arch to train at full size "
-                         "(coin_gcn: a Table-I dataset, e.g. nell)")
+                         "(coin_gcn: a Table-I dataset, e.g. nell; graphcast: era5_1deg)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=1e-3)

@@ -79,15 +79,15 @@ def test_gnn_smoke(arch_id):
         assert out.shape == (n, cfg.d_out)
         loss_fn = lambda p: jnp.mean(fwd(p, feats, s, r, cfg) ** 2)
     elif arch_id == "graphcast":
-        from repro.models.graphcast import graphcast_forward as fwd, graphcast_init as init
+        from repro.models.graphcast import graphcast_forward as fwd, graphcast_graph, graphcast_init as init
 
-        cfg2 = cfg
-        x = feats[:, : cfg2.input_dim] if cfg2.input_dim <= feats.shape[1] else jnp.tile(feats, (1, 2))[:, : cfg2.input_dim]
-        ef = jnp.ones((s.shape[0], cfg2.d_edge_in))
-        params = init(KEY, cfg2)
-        out = fwd(params, x, ef, s, r, cfg2)
-        assert out.shape == (n, cfg2.n_vars)
-        loss_fn = lambda p: jnp.mean(fwd(p, x, ef, s, r, cfg2) ** 2)
+        graph = {k: jnp.asarray(v) for k, v in graphcast_graph(cfg).arrays().items()}
+        n_grid = graph["grid_nodes"].shape[0]
+        b = dict(graph, grid_inputs=jax.random.normal(KEY, (n_grid, cfg.d_grid_in)))
+        params = init(KEY, cfg)
+        out = jax.jit(fwd, static_argnums=2)(params, b, cfg)
+        assert out.shape == (n_grid, cfg.n_vars)
+        loss_fn = jax.jit(lambda p: jnp.mean(fwd(p, b, cfg) ** 2))
     else:
         from repro.models.equiformer_v2 import equiformer_forward as fwd, equiformer_init as init
 
@@ -143,7 +143,8 @@ def test_registry_covers_40_cells():
         if a == "coin_gcn":
             continue
         cells += len(get_arch(a).shapes)
-    assert cells == 40
+    # graphcast has one shape, its published grid and mesh (era5_1deg)
+    assert cells == 37
     # long_500k runs exactly for the sub-quadratic LM arch (gemma3).
     runnable_500k = [
         a for a in ALL_ARCHS
