@@ -24,7 +24,9 @@
   its length, both over the longest edge of that edge set, so every length
   lies in [0, 1].
 
-Edge arrays are ordered by receiver, then sender.
+Edge arrays are ordered by receiver, then sender. Each edge set carries
+the layouts of `repro.graph.ops.sorted_segment_sum` for its receivers and,
+through the permutation that orders its edges by sender, for its senders.
 """
 from __future__ import annotations
 
@@ -33,6 +35,7 @@ import functools
 
 import numpy as np
 
+from repro.graph.ops import sorted_layout
 from repro.obs import trace as _obs_trace
 
 __all__ = [
@@ -51,6 +54,7 @@ __all__ = [
     "edge_features",
     "build_graph",
     "graph_sizes",
+    "graph_shapes",
 ]
 
 
@@ -226,6 +230,21 @@ def edge_features(sender_xyz: np.ndarray, receiver_xyz: np.ndarray,
     return (np.concatenate([length, local], axis=-1) / top).astype(np.float32)
 
 
+def edge_layouts(name: str, senders: np.ndarray, receivers: np.ndarray,
+                 n_senders: int, n_receivers: int) -> dict:
+    """The sorted-sum layouts of one edge set (ordered by receiver), keyed
+    as `GraphCastGraph.arrays` gives them: ``<name>_recv_{tiles,pairs}``
+    over the receivers; ``<name>_send_order`` (the stable permutation into
+    sender order) and ``<name>_send_{tiles,pairs}`` over the senders in
+    that order."""
+    order = np.argsort(senders, kind="stable")
+    out = {f"{name}_send_order": order.astype(np.int32)}
+    for side, ids, n in (("recv", receivers, n_receivers), ("send", senders[order], n_senders)):
+        out.update(zip((f"{name}_{side}_{k}" for k in ("tiles", "pairs")),
+                       sorted_layout(ids, n)))
+    return out
+
+
 @dataclasses.dataclass(frozen=True)
 class GraphCastGraph:
     """The three graphs of one GraphCast model, host numpy. Index arrays
@@ -243,6 +262,7 @@ class GraphCastGraph:
     m2g_senders: np.ndarray     # mesh nodes
     m2g_receivers: np.ndarray   # grid nodes
     m2g_edges: np.ndarray
+    layouts: dict               # `edge_layouts` of the three edge sets
 
     @property
     def sizes(self) -> dict:
@@ -251,11 +271,26 @@ class GraphCastGraph:
                 "n_g2m": int(self.g2m_senders.shape[0]), "n_m2g": int(self.m2g_senders.shape[0])}
 
     def arrays(self) -> dict:
-        """The structural entries of a GraphCast batch."""
-        return {k: getattr(self, k) for k in (
+        """The structural entries of a GraphCast batch: node and edge
+        features, each edge set's senders and receivers, and the layouts
+        with which `repro.models.graphcast` sums over them with no scatter
+        (`edge_layouts`, `repro.graph.ops.sorted_layout`).
+
+        For each edge set and each of its two index arrays, the edges in
+        ascending order of that index (as they are for the receivers; by
+        ``<name>_send_order`` for the senders) are cut into tiles of
+        `repro.graph.ops.SORTED_TILE` edges, ``*_tiles``
+        ``(n_tiles, 1, SORTED_TILE)`` their node ids; the nodes into blocks
+        of 128 rows; ``*_pairs`` ``(3, P)`` lists, block by block, the tiles
+        that hold each block's edges. ``n_tiles`` and ``P`` are read from
+        the graph: ``P`` is about the blocks plus the tiles that straddle two
+        blocks. The sums are float32: the messages enter the MXU as exact
+        bfloat16 parts, accumulated in float32."""
+        out = {k: getattr(self, k) for k in (
             "grid_nodes", "mesh_nodes", "mesh_senders", "mesh_receivers", "mesh_edges",
             "g2m_senders", "g2m_receivers", "g2m_edges",
             "m2g_senders", "m2g_receivers", "m2g_edges")}
+        return dict(out, **self.layouts)
 
 
 def build_graph(resolution: float, splits: int, min_level: int,
@@ -279,6 +314,10 @@ def build_graph(resolution: float, splits: int, min_level: int,
         m2g_s, m2g_r = _by_receiver(faces.reshape(-1), np.repeat(np.arange(grid_xyz.shape[0]), 3))
 
         mlat, mlon = _xyz_to_latlon(mesh_xyz)
+        n_grid, n_mesh = grid_xyz.shape[0], mesh_xyz.shape[0]
+        layouts = {**edge_layouts("mesh", ms, mr, n_mesh, n_mesh),
+                   **edge_layouts("g2m", gs, gr, n_grid, n_mesh),
+                   **edge_layouts("m2g", m2g_s, m2g_r, n_mesh, n_grid)}
         return GraphCastGraph(
             mesh_xyz=mesh_xyz,
             grid_nodes=node_features(glat, glon), mesh_nodes=node_features(mlat, mlon),
@@ -288,6 +327,7 @@ def build_graph(resolution: float, splits: int, min_level: int,
             g2m_edges=edge_features(grid_xyz[gs], mesh_xyz[gr], mlat[gr], mlon[gr]),
             m2g_senders=m2g_s, m2g_receivers=m2g_r,
             m2g_edges=edge_features(mesh_xyz[m2g_s], grid_xyz[m2g_r], glat[m2g_r], glon[m2g_r]),
+            layouts=layouts,
         )
 
 
@@ -297,3 +337,12 @@ def graph_sizes(resolution: float, splits: int, min_level: int,
     """`GraphCastGraph.sizes` of a geometry, as sorted pairs (the build is
     seconds at 1°; abstract cells and FLOP counts ask for it repeatedly)."""
     return tuple(sorted(build_graph(resolution, splits, min_level, radius_fraction).sizes.items()))
+
+
+@functools.lru_cache(maxsize=8)
+def graph_shapes(resolution: float, splits: int, min_level: int,
+                 radius_fraction: float) -> tuple[tuple[str, tuple[int, ...], str], ...]:
+    """``(key, shape, dtype)`` of each entry of `GraphCastGraph.arrays` of a
+    geometry, for abstract batches."""
+    graph = build_graph(resolution, splits, min_level, radius_fraction)
+    return tuple((k, v.shape, str(v.dtype)) for k, v in sorted(graph.arrays().items()))

@@ -7,6 +7,9 @@
                     pallas_call (fp32 accumulation, optional bf16 operands)
   fm_interaction  — DeepFM linearized second-order interaction
   flash_attention — causal/sliding-window online-softmax attention
+  sorted_sum      — sums of receiver-sorted edge rows into 128-row node
+                    blocks (one-hot tiles on the MXU; `repro.graph.ops`
+                    `sorted_segment_sum` wraps it)
 
 Each kernel ships with a pure-jnp oracle in `ref.py` and a jit'd public
 wrapper in `ops.py` (interpret mode on CPU, native on TPU). The kernel

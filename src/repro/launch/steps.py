@@ -372,19 +372,13 @@ def _gnn_sizes(shape: ShapeSpec, pad_mult: int) -> tuple[int, int]:
 def _graphcast_batch_abstract(cfg, lead: tuple = ()) -> dict:
     """Abstract GraphCast batch (`repro.models.graphcast`), sized by the
     geometry `repro.graph.sphere` builds for ``cfg``."""
-    from repro.graph.sphere import graph_sizes
+    from repro.graph.sphere import graph_shapes
 
-    z = dict(graph_sizes(*cfg.geometry))
-    batch = {
-        "grid_inputs": _sds(lead + (z["n_grid"], cfg.d_grid_in), F32),
-        "grid_target": _sds(lead + (z["n_grid"], cfg.n_vars), F32),
-        "grid_nodes": _sds(lead + (z["n_grid"], 3), F32),
-        "mesh_nodes": _sds(lead + (z["n_mesh"], 3), F32),
-    }
-    for name, n_edges in (("mesh", z["n_mesh_edges"]), ("g2m", z["n_g2m"]), ("m2g", z["n_m2g"])):
-        batch[f"{name}_senders"] = _sds(lead + (n_edges,), I32)
-        batch[f"{name}_receivers"] = _sds(lead + (n_edges,), I32)
-        batch[f"{name}_edges"] = _sds(lead + (n_edges, 4), F32)
+    batch = {k: _sds(lead + shape, jnp.dtype(dtype))
+             for k, shape, dtype in graph_shapes(*cfg.geometry)}
+    n_grid = batch["grid_nodes"].shape[-2]
+    batch["grid_inputs"] = _sds(lead + (n_grid, cfg.d_grid_in), F32)
+    batch["grid_target"] = _sds(lead + (n_grid, cfg.n_vars), F32)
     return batch
 
 

@@ -18,7 +18,10 @@ t (with forcings and static fields) on a lat-lon grid to the state at t+6h.
 * Mesh2Grid: one interaction network over mesh→grid; then an output MLP
   (no LayerNorm) on the grid latents, added to the state at t.
 * Every MLP: one hidden layer of ``d_latent`` with swish, a LayerNorm on
-  its output (`repro.nn.layers`). Aggregation is a sum.
+  its output (`repro.nn.layers`). Aggregation is a sum, taken over the
+  receiver-sorted edges with no scatter (`repro.graph.ops.sorted_segment_sum`);
+  the gathers of sender and receiver latents differentiate into the same
+  sums (`repro.graph.ops.sorted_gather`).
 * Loss: GraphCast's weighted MSE, ``mean_n a_n Σ_c w_c (pred − target)²``:
   ``a_n`` the grid cell's area over the mean (cos latitude times the
   band's width; the pole cells' caps), ``w_c`` the variable's weight
@@ -40,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.graph.ops import sorted_gather, sorted_segment_sum
 from repro.graph.sphere import GraphCastGraph, build_graph, graph_sizes, latlon_grid
 from repro.nn.layers import layer_norm, mlp_apply, mlp_init
 
@@ -126,11 +130,23 @@ def graphcast_init(key: jax.Array, cfg: GraphCastConfig) -> dict:
     }
 
 
-def _interaction(p: dict, e, h_send, h_recv, senders, receivers):
+def _edge_set(batch: dict, name: str) -> dict:
+    """One edge set's indices and sorted-sum layouts (`GraphCastGraph.arrays`)."""
+    return {k: batch[f"{name}_{k}"] for k in (
+        "senders", "receivers", "send_order", "recv_tiles", "recv_pairs", "send_tiles",
+        "send_pairs")}
+
+
+def _interaction(p: dict, e, h_send, h_recv, g: dict):
     """One interaction network step with residuals; returns the new edge
-    and receiver latents. Messages are the new edge latents, summed."""
-    e_new = _block(p["edge"], jnp.concatenate([e, h_send[senders], h_recv[receivers]], axis=-1))
-    agg = jax.ops.segment_sum(e_new, receivers, num_segments=h_recv.shape[0])
+    and receiver latents. Messages are the new edge latents, summed. The
+    sum and the two gathers' gradients are sorted sums over ``g``'s
+    layouts (`repro.graph.ops`): no scatter, forward or backward."""
+    recv = g["recv_tiles"], g["recv_pairs"]
+    sent = sorted_gather(h_send, g["senders"], g["send_order"], g["send_tiles"], g["send_pairs"])
+    got = sorted_gather(h_recv, g["receivers"], None, *recv)
+    e_new = _block(p["edge"], jnp.concatenate([e, sent, got], axis=-1))
+    agg = sorted_segment_sum(e_new, *recv, h_recv.shape[0])
     h_new = _block(p["node"], jnp.concatenate([h_recv, agg], axis=-1))
     return e + e_new, h_recv + h_new
 
@@ -148,23 +164,22 @@ def graphcast_forward(params: dict, batch: dict, cfg: GraphCastConfig) -> jnp.nd
 
     with jax.named_scope("graphcast.grid2mesh"):
         p = params["grid2mesh"]
-        _, h_mesh = _interaction(p, e_g2m, h_grid, h_mesh,
-                                 batch["g2m_senders"], batch["g2m_receivers"])
+        _, h_mesh = _interaction(p, e_g2m, h_grid, h_mesh, _edge_set(batch, "g2m"))
         h_grid = h_grid + _block(p["grid"], h_grid)
 
     with jax.named_scope("graphcast.processor"):
-        s, r = batch["mesh_senders"], batch["mesh_receivers"]
+        mesh = _edge_set(batch, "mesh")
 
         @_checkpoint
         def layer(carry, p):
             e, h = carry
-            return _interaction(p, e, h, h, s, r), None
+            return _interaction(p, e, h, h, mesh), None
 
         (_, h_mesh), _ = jax.lax.scan(layer, (e_mesh, h_mesh), params["processor"])
 
     with jax.named_scope("graphcast.mesh2grid"):
         _, h_grid = _interaction(params["mesh2grid"], e_m2g, h_mesh, h_grid,
-                                 batch["m2g_senders"], batch["m2g_receivers"])
+                                 _edge_set(batch, "m2g"))
         out = mlp_apply(params["output"], h_grid)
     state = x[:, (cfg.n_input_steps - 1) * cfg.n_vars: cfg.n_input_steps * cfg.n_vars]
     return state + out

@@ -113,6 +113,26 @@ def test_fused_gcn_layer_compiles(one_chip, order, f_in, dtype):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("edges,side,nodes", [
+    ("mesh", "recv", "mesh"),     # the multimesh: 64 calls a step
+    ("g2m", "send", "grid"),      # Grid2Mesh's senders: 510 node blocks
+    ("m2g", "recv", "grid"),      # Mesh2Grid's receivers: the most edges
+])
+def test_sorted_sum_compiles_at_graphcast_width(one_chip, edges, side, nodes):
+    """GraphCast_small's sorted sums at latent 512, their layouts read from
+    the 1° geometry."""
+    from repro.graph.sphere import graph_shapes
+    from repro.kernels.sorted_sum import sorted_sum_pallas
+
+    shapes = {k: shape for k, shape, _ in graph_shapes(1.0, 5, 2, 0.6)}
+    x = _sds((shapes[f"{edges}_senders"][0], 512), jnp.float32, one_chip)
+    tiles = _sds(shapes[f"{edges}_{side}_tiles"], jnp.int32, one_chip)
+    pairs = _sds(shapes[f"{edges}_{side}_pairs"], jnp.int32, one_chip)
+    text = sorted_sum_pallas.lower(x, tiles, pairs, shapes[f"{nodes}_nodes"][0],
+                                   interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text and "scatter" not in text
+
+
 def _compile_bsr_train_step(cfg, n, e, tables, sharding):
     """The jitted train step of ``coin_gcn`` on the bsr backend, compiled for
     an ``n``-node, ``e``-edge graph (self-loops included) with ``tables``

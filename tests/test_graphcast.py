@@ -181,4 +181,5 @@ def test_dry_run_cell_lowers_data_parallel():
     cell = build_cell(spec, spec.shapes["era5_1deg"], make_local_mesh())
     assert cell.abstract_args[2]["grid_inputs"].shape == (1, 312, CFG.d_grid_in)
     assert cell.model_flops == 3.0 * gc.forward_flops(CFG)
-    assert "scatter" in cell.lower(make_local_mesh()).as_text()
+    text = cell.lower(make_local_mesh()).as_text()
+    assert "gather" in text and "scatter" not in text          # sorted sums, no scatter
