@@ -1,8 +1,10 @@
 """Benchmark driver: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV (harness contract). Fast by default;
-``--full`` adds the slower quantization sweep over more datasets and the
-roofline rows for the multi-pod mesh.
+Prints ``name,us_per_call,derived`` CSV. The rows are the paper's
+analytical tables and the deterministic count gates; ``us_per_call`` is a
+host CPU timing, not a speed of the system (that comes from
+``python3 -m bench.run`` on the chip). Fast by default; ``--full`` adds the
+slower quantization sweep over more datasets.
 """
 from __future__ import annotations
 
@@ -24,7 +26,6 @@ def main(argv=None) -> None:
     from benchmarks.relocal_bench import relocal_rows
     from benchmarks.fig07_quant import fig07_quant_accuracy
     from benchmarks.kernel_bench import bench_kernels_rows, kernel_rows, spmm_compare_rows
-    from benchmarks.serve_bench import serve_rows
     from benchmarks.paper_figs import (
         comm_tier_rows,
         fig01_baseline_comm,
@@ -41,7 +42,6 @@ def main(argv=None) -> None:
         tbl_dataflow,
         tbl_optimal_k,
     )
-    from benchmarks.roofline import roofline_rows
 
     suites = [
         ("fig01", fig01_baseline_comm),
@@ -63,16 +63,12 @@ def main(argv=None) -> None:
         ("kernels", kernel_rows),
         ("kernels-ragged", bench_kernels_rows),
         ("spmm", lambda: spmm_compare_rows(full=args.full)),
-        ("serve", serve_rows),
         ("obs", obs_rows),
         ("fig07", lambda: fig07_quant_accuracy(
             datasets=("cora", "citeseer", "pubmed") if args.full else ("cora",),
             epochs=120,
         )),
-        ("roofline-16x16", lambda: roofline_rows("16x16")),
     ]
-    if args.full:
-        suites.append(("roofline-2x16x16", lambda: roofline_rows("2x16x16")))
 
     print("name,us_per_call,derived")
     failures = 0

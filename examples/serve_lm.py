@@ -1,6 +1,5 @@
 """Serving example (deliverable b): batched prefill + KV-cache decode with a
-reduced gemma3-style sliding-window LM — the serve path the decode_32k /
-long_500k dry-run cells lower at production scale.
+reduced gemma3-style sliding-window LM.
 
     PYTHONPATH=src python examples/serve_lm.py
 """

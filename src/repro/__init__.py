@@ -13,7 +13,8 @@ Layers:
   repro.train     — optimizers, loop, checkpointing, compression, elasticity.
   repro.dist      — mesh/sharding utilities and collective helpers.
   repro.configs   — one config per assigned architecture.
-  repro.launch    — production mesh, multi-pod dry-run, train/serve drivers.
+  repro.launch    — meshes, GNN step functions and cell builder, train/serve
+                    drivers.
 """
 
 __version__ = "1.0.0"

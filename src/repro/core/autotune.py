@@ -24,7 +24,8 @@ The three pieces:
     composing the per-tier ``exchange_cost`` wire/exposed bytes, the
     ``blocked_multiply_count`` executed-tile compute, and the
     ``CoinEnergyModel``/``MeshNoC`` energy+latency models. Its comm fields
-    use the *same formulas* as the measured dry-run ``exchange_accounting``,
+    use the *same formulas* as the measured ``exchange_accounting``
+    (``repro.launch.steps``),
     so prediction-vs-measurement is an exact-field comparison (pinned in
     tests/test_autotune.py).
   * :func:`autotune_config` — coordinate descent: the pod_map knob moves by
@@ -119,7 +120,7 @@ class CommStats:
     Derivable two ways — analytically from a :class:`BoundaryIndex`
     (``index.comm_stats``) or from a built plan
     (:func:`comm_stats_from_plan`); the two agree exactly, which is the
-    calibration contract the dry-run ``predicted`` block pins.
+    calibration contract tests/test_autotune.py pins.
     """
 
     k: int
@@ -349,8 +350,9 @@ def predict_config_cost(
 ) -> dict:
     """Analytic cost of one candidate config — the search's objective.
 
-    The comm fields reproduce the dry-run ``exchange_accounting`` formulas
-    verbatim (same names, same units), so a plan built from ``cfg`` measures
+    The comm fields reproduce the formulas of
+    ``repro.launch.steps.exchange_accounting`` verbatim (same names, same
+    units), so a plan built from ``cfg`` measures
     exactly what this predicts for every deterministic field — the pinned
     calibration contract. The scalar lives under ``"objective_s"``; the
     breakdown terms and their units are in the module docstring and

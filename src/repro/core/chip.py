@@ -11,7 +11,7 @@ mapped "as is" onto crossbars (crossbar-granular: ⌈N/128⌉ × ⌈cols/128⌉
 arrays), plus the layer weights. We reproduce the paper's counts for
 Cora/Citeseer/Pubmed under 1-cell-per-adjacency-entry crossbar-granular
 mapping; for Ext. Cora/Nell the paper's exact bookkeeping is underdetermined
-(see EXPERIMENTS.md) and we report our model's counts alongside the paper's.
+and we report our model's counts alongside the paper's.
 """
 from __future__ import annotations
 
@@ -82,7 +82,7 @@ def chips_required(
       Nell (45) exactly.
     mode="cell" — cell-granular capacity (N²·cells / chip cells). Reproduces
       Pubmed (3). Ext. Cora's published 20 is not derivable from the stated
-      parameters under either accounting (see EXPERIMENTS.md note).
+      parameters under either accounting.
     """
     if mode == "cell":
         cells = n_nodes * n_nodes * model.adj_cells_per_entry

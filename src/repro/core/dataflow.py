@@ -19,9 +19,8 @@ tiles skipped by the ragged kernel (DESIGN.md §2). Its cost term is
 therefore ``nnz_blocks · B² · F`` — a graph whose communities pack into few
 tiles aggregates cheaper than its edge count suggests, and a shuffled graph
 pays for every smeared tile. `blocked_multiply_count` models it and
-`choose_order(backend="bsr", nnz_blocks=…)` uses it; the dry-run threads the
-same statistics into its FLOP accounting so hillclimb compares real kernel
-cost (`repro.launch.dryrun`).
+`choose_order(backend="bsr", nnz_blocks=…)` uses it, and a bsr cell's
+`model_flops` counts it (`repro.launch.steps`).
 
 This module provides the cost models and the order chooser used by the GCN
 layer (`repro.models.gcn`) at trace time.
@@ -123,8 +122,8 @@ class ExchangeCost:
 def exchange_cost(
     rows: int, d: int, payload_bits: int = 32, overlap_fraction: float = 0.0
 ) -> ExchangeCost:
-    """Convenience constructor for :class:`ExchangeCost` (dry-run accounting,
-    hillclimb prints, and the ``choose_order`` exchange term)."""
+    """Convenience constructor for :class:`ExchangeCost` (the cells'
+    ``exchange_accounting`` and the ``choose_order`` exchange term)."""
     return ExchangeCost(
         rows=int(rows), d=int(d), payload_bits=int(payload_bits),
         overlap_fraction=float(overlap_fraction),
@@ -143,7 +142,7 @@ def choose_order(
     per-nonzero-block model (``backend="bsr"`` with ``nnz_blocks``) — the
     comparison reduces to d_out vs d_in (the N·F·H term is shared), so the
     chooser is exact for any accounting; what changes between models is the
-    cost *magnitude*, which the dry-run/hillclimb FLOP accounting consumes.
+    cost *magnitude*.
     Ties go to feature-first (the paper's order).
 
     ``halo_rows`` adds the exchange term of the sharded halo schedule:
@@ -152,8 +151,8 @@ def choose_order(
     overlap/compression model ``payload_bits/32 · (1 − overlap_fraction)``
     (:class:`ExchangeCost`, in element-equivalents). The term moves with the
     SAME d_out-vs-d_in sign as the compute terms, so the argmax is unchanged
-    — it exists so hillclimb and the dry-run see exchange-aware magnitudes,
-    not to flip decisions.
+    — it exists to make the magnitudes exchange-aware, not to flip
+    decisions.
     """
     if backend == "bsr" and nnz_blocks is not None:
         cost = blocked_multiply_count(n_nodes, nnz_blocks, d_in, d_out, block)

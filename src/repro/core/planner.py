@@ -18,8 +18,7 @@ We model one GCN layer under the COIN schedule on k shards:
 
 and pick the k (divisor of the available devices) that minimizes the max of
 the two timed terms — the same "balance intra vs inter" insight as Eq. 3,
-expressed in seconds instead of joules. This drives the default shardings in
-`repro.launch` and is exercised by the hillclimb in EXPERIMENTS.md §Perf.
+expressed in seconds instead of joules (tests/test_planner_scheduler.py).
 """
 from __future__ import annotations
 

@@ -87,8 +87,7 @@ def fake_quant(x: jax.Array, bits: int, percentile: float | None = None) -> jax.
     bits ≥ 32 (or ≤ 0) is a no-op (fp32 reference). The scale is amax-based
     by default; ``percentile`` clips the calibration range (e.g. 99.9) — at
     ≤4 bits GCN aggregation outputs have heavy degree-driven outliers and a
-    pure-amax scale wastes most of the code points (§V-B reproduction note
-    in EXPERIMENTS.md).
+    pure-amax scale wastes most of the code points (§V-B reproduction note).
     """
     if bits >= 32 or bits <= 0:
         return x

@@ -11,8 +11,7 @@ increasing) on the range of interest and convex in a neighborhood of the
 minimizer, so the interior-point conclusion stands. We therefore run a
 golden-section localization over the full feasible range (robust to the
 non-convex tail) followed by a log-barrier damped-Newton polish (the paper's
-method, valid in the locally convex basin). The discrepancy is recorded in
-EXPERIMENTS.md.
+method, valid in the locally convex basin).
 """
 from __future__ import annotations
 

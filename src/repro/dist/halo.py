@@ -315,7 +315,7 @@ class HaloPlan:
         """Fraction of real aggregation work with NO halo dependence — the
         interior compute available to hide the exchange behind (the
         ``1 − overlap_fraction`` of the exposed-bytes model in
-        docs/communication.md and the dry-run `exchange` accounting)."""
+        docs/communication.md and `repro.launch.steps.exchange_accounting`)."""
         loc = self._edge_locality()
         total = loc["interior_edges"] + loc["boundary_edges"]
         return loc["interior_edges"] / total if total else 0.0
@@ -342,8 +342,9 @@ class HaloPlan:
         return (jnp.asarray(self.send_idx, jnp.int32),) + tail
 
     def abstract_inputs(self) -> tuple[jax.ShapeDtypeStruct, ...]:
-        """ShapeDtypeStructs mirroring :meth:`device_arrays` (dry-run path):
-        4-tuple for flat plans, 5-tuple for hierarchical ones."""
+        """ShapeDtypeStructs mirroring :meth:`device_arrays` (abstract
+        cells of `repro.launch.steps`): 4-tuple for flat plans, 5-tuple for
+        hierarchical ones."""
         tail = (
             jax.ShapeDtypeStruct((self.k, self.e_local), jnp.int32),
             jax.ShapeDtypeStruct((self.k, self.e_local), jnp.int32),
@@ -933,7 +934,7 @@ class PlanBlockedAdjacency:
         return 1.0 - self.nnz_blocks / max(grid, 1)
 
     def stats(self) -> dict:
-        """The dry-run / benchmark accounting record (all static host ints)."""
+        """The benchmark accounting record (all static host ints)."""
         return {
             "block": self.block,
             "n_block_rows": self.n_block_rows,
@@ -952,7 +953,7 @@ class PlanBlockedAdjacency:
         )
 
     def abstract_inputs(self) -> tuple[jax.ShapeDtypeStruct, ...]:
-        """ShapeDtypeStructs mirroring :meth:`device_arrays` (dry-run path)."""
+        """ShapeDtypeStructs mirroring :meth:`device_arrays` (abstract cells)."""
         k, R, T, B = self.k, self.n_block_rows, self.max_nnzb, self.block
         return (
             jax.ShapeDtypeStruct((k, R, T, B, B), jnp.float32),
@@ -976,7 +977,7 @@ def plan_blocked_shape(plan: HaloPlan, block: int = 128) -> dict:
 
     Counts each device's distinct (receiver-block, sender-block) pairs over
     the real edges — O(E) ints, no (…, B, B) allocation — so abstract
-    dry-run cells (`repro.launch.steps`) can size ``backend="bsr"`` batch
+    cells (`repro.launch.steps`) can size ``backend="bsr"`` batch
     entries and report nonzero-block / padded-tile accounting at shapes
     (ogbn-products) where materializing the tiles would not fit. Returns the
     :meth:`PlanBlockedAdjacency.stats` dict plus ``n_rows``/``n_cols``.
@@ -1127,7 +1128,7 @@ def plan_split_blocked_adjacency(
 def plan_split_blocked_shape(plan: HaloPlan, block: int = 128) -> dict:
     """:func:`plan_blocked_shape` for the split pair — O(E) statistics, no
     tiles. Returns ``{"interior": stats, "boundary": stats,
-    "overlap_fraction": f}`` so abstract dry-run cells can size the two
+    "overlap_fraction": f}`` so abstract cells can size the two
     ragged tables and report how much aggregation work hides the wire.
     """
     out = {}

@@ -263,7 +263,7 @@ def blocked_stats(
 
     O(E) integer work — usable at ogbn-products scale where the dense tiles
     of :func:`blocked_adjacency` would not fit. Returns the layout record
-    the benchmarks and the dry-run report: ``n_block_rows`` (R),
+    the benchmarks report: ``n_block_rows`` (R),
     ``max_nnzb`` (T, the dense-T pad), ``nnz_blocks`` (tiles the ragged
     kernel executes), ``dense_tiles`` (R·T, tiles a dense-T kernel
     executes), and ``padded_tile_fraction`` (the dense-T waste the ragged

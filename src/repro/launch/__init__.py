@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launchers: meshes, GNN step functions and cell builder, train/serve drivers."""

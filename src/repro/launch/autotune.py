@@ -4,10 +4,9 @@ The CLI face of ``repro.core.autotune`` (docs/autotune.md): builds the pinned
 graph + partition, runs the quotient-graph pod mapper and the
 coordinate-descent config search, then re-measures BOTH the default and the
 chosen config on really-built halo plans and prints a predicted-vs-measured
-report. The chosen config is written as JSON (``--out``) in a form the other
-drivers consume — ``repro.launch.dryrun --autotune-config <file>`` applies
-it directly, and the report prints the matching flags for
-``examples/train_distributed_gcn.py`` / ``repro.launch.serve``.
+report. The chosen config is written as JSON (``--out``), and the report
+prints the matching flags for ``examples/train_distributed_gcn.py`` /
+``repro.launch.serve``.
 
 Everything here is host-side numpy: no mesh, no jax compile, so the search
 runs in seconds even for the 16384-node benchmark graphs.
@@ -39,7 +38,7 @@ __all__ = ["measured_accounting", "run_autotune", "main"]
 def measured_accounting(plan, cfg: CandidateConfig, d_feat: int) -> dict:
     """Measured comm/compute record of one config on a BUILT plan.
 
-    Same field names and formulas as the dry-run ``exchange_accounting`` —
+    Same field names and formulas as ``repro.launch.steps.exchange_accounting`` —
     this is the "measured" side of the predicted-vs-measured report and of
     BENCH_autotune.json (rows come from the plan's real export tables, not
     from the analytic index).
@@ -193,8 +192,6 @@ def _print_report(rec: dict) -> None:
         print("calibration: every shared predicted field matches measured exactly")
     pods, payload = cfg["pods"], cfg["payload"] or "fp32"
     print("hand-off:")
-    print(f"  dryrun: PYTHONPATH=src python -m repro.launch.dryrun --arch coin-gcn "
-          f"--autotune-config <out.json>")
     print(f"  train : PYTHONPATH=src python examples/train_distributed_gcn.py "
           f"--pods {pods}" + (f" --payload {payload}" if payload != "fp32" else ""))
     print(f"  serve : PYTHONPATH=src python -m repro.launch.serve --arch coin-gcn "

@@ -1,11 +1,10 @@
-"""Production mesh construction (multi-pod dry-run contract, DESIGN.md §6).
+"""Mesh construction (DESIGN.md §6).
 
 FUNCTIONS, not module-level constants — importing this module never touches
-jax device state. Single-pod: 16×16 = 256 chips, axes (data, model).
-Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model); `pod` composes with
-`data` for gradient reduction / replica serving, and with `model` for the
-hierarchical (pod, model) halo exchange of full-graph GNN cells
-(docs/communication.md).
+jax device state. Axes are (data, model), or (pod, …) with a pod tier;
+`pod` composes with `data` for gradient reduction / replica serving, and
+with `model` for the hierarchical (pod, model) halo exchange of full-graph
+GNN cells (docs/communication.md).
 """
 from __future__ import annotations
 
@@ -14,7 +13,6 @@ from jax.sharding import AxisType
 
 __all__ = [
     "make_mesh",
-    "make_production_mesh",
     "make_local_mesh",
     "make_halo_mesh",
     "data_axes",
@@ -36,14 +34,8 @@ def make_mesh(shape, axes, *, devices=None):
     )
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_local_mesh():
-    """1-device mesh with the production axis names (CPU tests/examples)."""
+    """1-device (data, model) mesh (CPU tests/examples)."""
     return make_mesh((1, 1), ("data", "model"))
 
 

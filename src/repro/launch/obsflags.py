@@ -1,6 +1,6 @@
 """Shared ``--trace`` / ``--metrics`` wiring for the launch drivers.
 
-Every `repro.launch` entrypoint (train, serve, dryrun) and the distributed
+Every `repro.launch` entrypoint (train, serve, autotune) and the distributed
 example accept the same two flags:
 
     --metrics out.json   enable `repro.obs.metrics`, write the deterministic

@@ -1,45 +1,21 @@
-"""Per-family sharding rules over the production mesh (DESIGN.md §6).
+"""GNN sharding rules over a (data, model) or (pod, …) mesh (DESIGN.md §6).
 
-Rules are path-based over the param pytrees and return NamedShardings; the
-activation-side policies built here are the name→PartitionSpec contract of
-DESIGN.md §7.1 (`repro.dist.policy.ShardingPolicy`). Defaults encode the
-COIN-derived plan:
-
-  LM     — Megatron TP over `model` (QKV/up column-, O/down row-parallel),
-           vocab-sharded embedding/logits, expert-parallel MoE weights,
-           batch over (pod, data).
-  GNN    — node/edge arrays sharded over `model` (the CE partition);
-           params replicated (tiny); sampled cells batch blocks over
-           (pod, data).
-  recsys — embedding table row-sharded over `model` (the COIN adjacency-
-           slice analogue); batch over (pod, data); MLP replicated.
-
-KV caches shard over kv-heads when divisible by the model axis, otherwise
-over sequence (the long-context path; batch 1 cells shard sequence over
-every available axis).
+Rules return NamedShardings; the activation-side policy built here is the
+name→PartitionSpec contract of DESIGN.md §7.1
+(`repro.dist.policy.ShardingPolicy`). Node/edge arrays shard over `model`
+(the CE partition); params are replicated (tiny); sampled cells batch
+blocks over (pod, data) in `repro.launch.steps`.
 """
 from __future__ import annotations
 
 from typing import Any
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.policy import ShardingPolicy
-from repro.launch.mesh import data_axes
 
-__all__ = [
-    "lm_param_specs",
-    "lm_policy",
-    "gnn_policy",
-    "recsys_policy",
-    "replicated_specs",
-    "recsys_param_specs",
-    "cache_spec",
-    "named",
-    "tree_named",
-]
+__all__ = ["gnn_policy", "replicated_specs", "named", "tree_named"]
 
 
 def named(mesh, spec: P) -> NamedSharding:
@@ -54,83 +30,12 @@ def tree_named(mesh, spec_tree: Any) -> Any:
     )
 
 
-def _model_size(mesh) -> int:
-    return int(np.prod([mesh.shape[a] for a in mesh.axis_names if a == "model"]))
-
-
-# ------------------------------------------------------------------------ LM
-def lm_param_specs(param_tree: Any, cfg, mesh) -> Any:
-    """PartitionSpec pytree mirroring the LM param pytree."""
-    msize = mesh.shape["model"]
-    # Shard K/V projections only when kv-heads split cleanly across the model
-    # axis; otherwise replicate (they are small: D × Hk·hd with Hk ∈ {1, 8}).
-    kv_shardable = cfg.n_kv_heads % msize == 0
-
-    def rule(path, leaf) -> P:
-        keys = [getattr(p, "key", getattr(p, "name", "")) for p in path]
-        name = "/".join(str(k) for k in keys)
-        nd = len(leaf.shape)
-        if "embed" in name or "lm_head" in name:
-            return P("model", None) if "embed" in name else P(None, "model")
-        if name.endswith("wq"):
-            return P(None, None, "model")
-        if name.endswith("wk") or name.endswith("wv"):
-            return P(None, None, "model") if kv_shardable else P(None, None, None)
-        if name.endswith("wo"):
-            return P(None, "model", None)
-        if "mlp" in name and name.endswith("w_down"):
-            return P(None, "model", None)
-        if "mlp" in name and ("w_gate" in name or "w_up" in name):
-            return P(None, None, "model")
-        if "moe" in name and "router" in name:
-            return P(None, None, None)
-        if "moe" in name and nd == 4:          # (L, E, D, F): expert parallel
-            return P(None, "model", None, None)
-        return P(*([None] * nd))
-
-    return jax.tree_util.tree_map_with_path(rule, param_tree)
-
-
-def lm_policy(mesh, cfg) -> ShardingPolicy:
-    da = data_axes(mesh)
-    return ShardingPolicy(
-        mesh=mesh,
-        specs={
-            "act": P(da, None, None),
-            "ffn_hidden": P(da, None, "model"),
-            "logits": P(da, None, "model"),
-            "dec_act": P(da, None, None),
-            "dec_logits": P(da, "model"),
-            # (groups, E, C, D) dispatch buffer: groups follow the data axes,
-            # experts the model axis → the EP all-to-all boundary.
-            "moe_buf": P(da, "model", None, None),
-        },
-    )
-
-
-def cache_spec(cfg, shape_spec, mesh) -> P:
-    """KV cache (L, B, S, Hk, Dh) sharding for decode cells."""
-    da = data_axes(mesh)
-    msize = mesh.shape["model"]
-    batch = shape_spec.global_batch
-    n_data = int(np.prod([mesh.shape[a] for a in da]))
-    if batch is not None and batch >= n_data and batch % n_data == 0:
-        batch_axes = da
-        if cfg.n_kv_heads % msize == 0:
-            return P(None, batch_axes, None, "model", None)
-        return P(None, batch_axes, "model", None, None)     # sequence-sharded
-    # batch too small (long-context, batch 1): shard sequence over everything.
-    all_axes = tuple(mesh.axis_names)
-    return P(None, None, all_axes, None, None)
-
-
-# ----------------------------------------------------------------------- GNN
 def replicated_specs(param_tree: Any) -> Any:
     return jax.tree_util.tree_map(lambda leaf: P(*([None] * len(leaf.shape))), param_tree)
 
 
 def gnn_policy(
-    mesh, batched: bool, comm: str = "halo",
+    mesh, comm: str = "halo",
     halo_payload: str | None = None, halo_overlap: bool = True,
 ) -> ShardingPolicy:
     """GNN activation policy. ``comm`` selects the full-graph communication
@@ -143,20 +48,10 @@ def gnn_policy(
     the wire format (bf16/int8 quantized payloads, dequantized on receive)
     and ``halo_overlap`` the interior/boundary-split schedule that hides the
     collective behind interior aggregation — both per docs/communication.md
-    "Overlapped schedule". Batched (sampled-block) cells have no cross-shard
-    edges, so the mode is irrelevant there."""
+    "Overlapped schedule". Sampled-block cells have no cross-shard edges
+    and run with no policy."""
     from repro.launch.mesh import halo_axes
 
-    da = data_axes(mesh)
-    if batched:
-        return ShardingPolicy(
-            mesh=mesh,
-            specs={
-                "node_hidden": P(da, None, None),
-                "edge_hidden": P(da, None, None),
-                "irrep_hidden": P(da, None, None, None),
-            },
-        )
     if comm not in ("halo", "broadcast"):
         raise ValueError(f"unknown comm mode {comm!r} (expected 'halo' or 'broadcast')")
     if comm == "halo":
@@ -175,26 +70,4 @@ def gnn_policy(
             "edge_hidden": P("model", None),
             "irrep_hidden": P("model", None, None),
         },
-    )
-
-
-# -------------------------------------------------------------------- recsys
-def recsys_param_specs(param_tree: Any) -> Any:
-    def rule(path, leaf) -> P:
-        name = "/".join(str(getattr(p, "key", "")) for p in path)
-        nd = len(leaf.shape)
-        if "table" in name:
-            return P("model", None)
-        if "w_linear" in name:
-            return P("model")
-        return P(*([None] * nd))
-
-    return jax.tree_util.tree_map_with_path(rule, param_tree)
-
-
-def recsys_policy(mesh) -> ShardingPolicy:
-    da = data_axes(mesh)
-    return ShardingPolicy(
-        mesh=mesh,
-        specs={"emb": P(da, None, None), "cand": P(None, "model", None)},
     )

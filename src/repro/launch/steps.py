@@ -1,22 +1,18 @@
-"""Cell builder: (arch × shape × mesh) → (step_fn, abstract inputs, shardings).
+"""GNN step functions and the GNN cell builder.
 
-`build_cell` returns everything `dryrun.py` needs to
-``jax.jit(fn, in_shardings, out_shardings).lower(*abstract_inputs)`` with no
-real allocation (every input is a ShapeDtypeStruct, params included — the
-same pattern the assignment's shannon/kernels reference uses).
+The main path: `gnn_loss_fn` is a GNN's loss on one device (the benchmark's
+training cells and ``launch.train`` call it); `halo_apply`, `halo_loss_fn`
+and `gcn_device_logits` run a full graph over a HaloPlan layout inside
+shard_map (DESIGN.md §8).
 
-Step kinds per family:
-  lm/train      — loss + grads + AdamW update        (train_step)
-  lm/prefill    — last-position logits               (serve_step)
-  lm/decode     — one token against the KV cache     (serve_step)
-  gnn/graph     — regression loss + grads + AdamW    (train_step; sampled
-                  cells vmap a block per data shard, graphcast one
-                  example per data shard)
-  recsys/train  — BCE loss + grads + AdamW
-  recsys/serve  — batched logits
-  recsys/retrieval — 1×N candidate scoring
+`build_cell` returns an abstract GNN train step — (arch × shape × mesh) →
+(step_fn, abstract inputs, shardings) — that
+``jax.jit(fn, in_shardings, out_shardings).lower(*abstract_inputs)``
+compiles with no real allocation (every input is a ShapeDtypeStruct, params
+included). Sampled cells vmap a block per data shard; graphcast trains one
+example per data shard.
 
-Full-graph GNN cells default to the **halo** communication schedule
+Full-graph cells default to the **halo** communication schedule
 (DESIGN.md §8): the step runs inside shard_map over a cached
 `repro.dist.halo.HaloPlan`, exchanging only boundary rows per layer
 (`k·s_max` received rows/device) instead of the broadcast all-gather
@@ -25,11 +21,13 @@ over ("pod", "model") jointly and the exchange turns hierarchical — two
 phases with per-tier padding, only deduplicated remote rows crossing the
 inter-pod fabric (DESIGN.md §8.3, docs/communication.md). Pass
 ``comm="broadcast"`` to `build_cell` for the paper-faithful Fig. 5c
-schedule (the escape hatch and the dry-run baseline).
+schedule. `exchange_accounting` reads a cell's wire rows off its plan, and
+`collective_bytes` sums the collectives of its compiled HLO.
 """
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Callable
 
 import jax
@@ -43,10 +41,12 @@ from repro.launch import shardings as sh
 from repro.launch.mesh import data_axes
 from repro.train.optimizer import adamw
 
-__all__ = ["Cell", "build_cell", "gnn_loss_fn", "halo_apply", "halo_loss_fn", "gcn_device_logits"]
+__all__ = [
+    "Cell", "build_cell", "exchange_accounting", "collective_bytes",
+    "gnn_loss_fn", "halo_apply", "halo_loss_fn", "gcn_device_logits",
+]
 
 F32 = jnp.float32
-BF16 = jnp.bfloat16
 I32 = jnp.int32
 
 
@@ -54,206 +54,161 @@ I32 = jnp.int32
 class Cell:
     arch_id: str
     shape_name: str
-    kind: str
     fn: Callable
     abstract_args: tuple
     in_shardings: Any
     out_shardings: Any
-    model_flops: float          # 6·N·D-style useful-FLOPs estimate
-    note: str = ""
-    # Cost correction: XLA cost_analysis counts a rolled lax.scan body ONCE,
-    # so deep layer stacks under-report FLOPs/bytes/collectives. When set,
-    # each entry is (small UNROLLED variant, its group count); the dry-run
-    # fits cost(g) = fixed + g·delta with delta clamped ≥ 0 (XLA's SPMD
-    # choices differ slightly between programs, so a raw two-point
-    # extrapolation can go negative) and evaluates at `cost_groups`. A single
-    # entry means "use its cost verbatim". memory_analysis / compile proof
-    # always come from this Cell's real rolled program.
-    cost_cells: list[tuple["Cell", float]] | None = None
-    cost_groups: float = 1.0
-    donate_argnums: tuple = ()
-    # GNN full-graph cells: which communication schedule the step uses
-    # ("halo" | "broadcast"; None for non-GNN / sampled cells) and, for halo,
-    # the HaloPlan whose static shapes the abstract batch follows — the
-    # dry-run reads wire accounting (k·s_max vs (k−1)·n_local) off it.
+    model_flops: float          # useful training FLOPs (3 × forward)
+    # Full-graph cells: which communication schedule the step uses
+    # ("halo" | "broadcast"; None for sampled and graphcast cells) and, for
+    # halo, the HaloPlan whose static shapes the abstract batch follows.
     comm: str | None = None
     halo_plan: Any = None
-    # backend="bsr" GCN cells: the blocked-adjacency statistics of
-    # `repro.dist.halo.plan_blocked_shape` (nonzero 128×128 tiles,
-    # padded-tile fraction) — the dry-run reports them in the `exchange`
-    # record and `model_flops` is computed from the blocked cost model
-    # (nnz_blocks·B²·F, repro.core.dataflow) instead of the edge count.
-    # Halo cells carry the split record of `plan_split_blocked_shape`
-    # ("interior"/"boundary" sub-dicts + combined top-level keys).
+    # backend="bsr" GCN halo cells: the split blocked-adjacency statistics
+    # of `repro.dist.halo.plan_split_blocked_shape` ("interior"/"boundary"
+    # sub-dicts + combined top-level keys); `model_flops` then follows the
+    # blocked cost model (nnz_blocks·B²·F, repro.core.dataflow).
     bsr_stats: dict | None = None
     # Halo cells: the wire payload format (None/"fp32" | "bf16" | "int8")
-    # and whether the interior/boundary-split overlapped schedule is on —
-    # the dry-run's exchange accounting reads both (ExchangeCost).
+    # and whether the interior/boundary-split overlapped schedule is on.
     halo_payload: str | None = None
     halo_overlap: bool = False
 
     def lower(self, mesh):
         jitted = jax.jit(
-            self.fn,
-            in_shardings=self.in_shardings,
-            out_shardings=self.out_shardings,
-            donate_argnums=self.donate_argnums,
+            self.fn, in_shardings=self.in_shardings, out_shardings=self.out_shardings
         )
         with mesh:
             return jitted.lower(*self.abstract_args)
+
+
+def exchange_accounting(cell, shape) -> dict | None:
+    """Analytic per-device wire rows of the GNN layer exchange (DESIGN.md §8,
+    docs/communication.md).
+
+    Halo cells carry their HaloPlan, so the reported bytes-moved reflects the
+    boundary rows each device actually receives — not the ``(k−1)·n_local``
+    a broadcast schedule would ship; both numbers are recorded so the wire
+    cut is visible. Hierarchical (pod, model) plans additionally split the
+    rows per tier — intra-pod (cheap links) vs inter-pod (rows crossing the
+    expensive fabric) — alongside the flat single-axis baseline on the same
+    partition. ``backend="bsr"`` GCN cells also carry their blocked-kernel
+    statistics (nonzero 128×128 tiles and the padded-tile fraction). Cells
+    without a plan (sampled or forced-broadcast) return just the comm tag.
+
+    The overlap/compression model (`repro.core.dataflow.ExchangeCost`,
+    docs/communication.md "Overlapped schedule") is reported alongside:
+    ``halo_wire_bytes_per_exchange`` is what crosses the fabric under the
+    cell's payload format (× bits/32 vs the fp32 total) and
+    ``halo_exposed_bytes_per_exchange`` what the critical path still waits
+    on (× (1 − overlap_fraction)).
+    """
+    plan = getattr(cell, "halo_plan", None)
+    if plan is None:
+        return {"comm": cell.comm} if getattr(cell, "comm", None) else None
+    from repro.core.dataflow import exchange_cost
+    from repro.core.quant import payload_bits
+
+    d = shape.d_feat or 0
+    payload = getattr(cell, "halo_payload", None)
+    bits = payload_bits(payload)
+    overlap = bool(getattr(cell, "halo_overlap", False))
+    ov_frac = plan.overlap_fraction() if overlap else 0.0
+    ec = exchange_cost(plan.halo_rows_per_device, d, bits, ov_frac)
+    out = {
+        "comm": cell.comm,
+        "halo_rows_per_device": plan.halo_rows_per_device,
+        "broadcast_rows_per_device": plan.broadcast_rows_per_device,
+        "wire_fraction": plan.wire_fraction(),
+        "halo_bytes_per_exchange": plan.halo_rows_per_device * d * 4,
+        "broadcast_bytes_per_exchange": plan.broadcast_rows_per_device * d * 4,
+        "payload": payload or "fp32",
+        "payload_bits": bits,
+        "payload_compression": ec.compression,
+        "overlap": overlap,
+        "overlap_fraction": ov_frac,
+        "halo_wire_bytes_per_exchange": ec.wire_bytes,
+        "halo_exposed_bytes_per_exchange": ec.exposed_bytes,
+        "boundary_rows_max_device": int(plan.boundary_rows_per_device().max(initial=0)),
+        "interior_rows_min_device": int(plan.interior_rows_per_device().min(initial=0)),
+    }
+    if getattr(cell, "bsr_stats", None):
+        out["bsr"] = dict(cell.bsr_stats)
+    if plan.is_hierarchical:
+        out.update(
+            axes=list(plan.axes),
+            pods=plan.n_pods,
+            intra_pod_rows_per_device=plan.intra_pod_rows_per_device,
+            inter_pod_rows_per_device=plan.inter_pod_rows_per_device,
+            inter_pod_rows_crossing=plan.inter_pod_rows_crossing,
+            flat_inter_pod_rows_crossing=plan.flat_inter_pod_rows_crossing,
+            inter_pod_bytes_crossing=plan.inter_pod_rows_crossing * d * 4,
+            flat_inter_pod_bytes_crossing=plan.flat_inter_pod_rows_crossing * d * 4,
+        )
+    return out
+
+
+_DTYPE_BYTES = {
+    "pred": 0.125, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
+    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
+}
+_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+_COLLECTIVES = (
+    "all-gather", "all-reduce", "reduce-scatter", "all-to-all", "collective-permute",
+)
+_COLL_RE = re.compile(
+    r"=\s*([^=]*?)\s+(" + "|".join(_COLLECTIVES) + r")(-start)?\("
+)
+
+
+def _shape_bytes(type_str: str) -> float:
+    """Bytes of one HLO shape string like 'bf16[256,4096]' or a tuple."""
+    total = 0.0
+    for dt, dims in _SHAPE_RE.findall(type_str):
+        if dt not in _DTYPE_BYTES:
+            continue
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        total += n * _DTYPE_BYTES[dt]
+    return total
+
+
+def collective_bytes(hlo_text: str) -> dict[str, float]:
+    """Sum result-shape bytes of every collective op in the post-SPMD HLO.
+
+    The result shape of each collective instruction line —
+    `%x = f32[170,75]{1,0} all-reduce(...)` — is per-device shaped after SPMD
+    partitioning, so the sum is the per-device wire volume entering the
+    network. `-done` ops carry the same tuple as their `-start`; only lines
+    that themselves name a collective op with an argument list are counted,
+    and `-done`/`-update` variants don't match the pattern.
+    """
+    out: dict[str, float] = {c: 0.0 for c in _COLLECTIVES}
+    for line in hlo_text.splitlines():
+        m = _COLL_RE.search(line)
+        if not m:
+            continue
+        out[m.group(2)] += _shape_bytes(m.group(1))
+    out["total"] = sum(out.values())
+    return out
 
 
 def _sds(shape, dtype) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(tuple(int(x) for x in shape), dtype)
 
 
-def _abstract_tree(tree):
-    return jax.tree_util.tree_map(lambda l: _sds(l.shape, l.dtype), tree)
-
-
-# ========================================================================= LM
-def _lm_cost_cells(
-    spec: ArchSpec, shape: ShapeSpec, mesh, cfg
-) -> tuple[list[tuple[Cell, float]], float]:
-    """Two small fully-unrolled variants for cost extrapolation.
-
-    period = the layer-pattern repeat (gemma3's 5:1 group, else 1 layer);
-    cost(L) ≈ fixed + (L/period)·delta. We lower g ∈ {2, 4} groups (or
-    {1, 2} when a group is multiple layers) and the dry-run fits the line
-    with the non-negative estimator (see Cell.cost_cells). kv_chunk is
-    raised to seq_len so the attention kv scan is also unrolled (single
-    chunk) inside the cost cells.
-    """
-    period = cfg.global_every or 1
-    if cfg.n_layers % period or cfg.n_layers < 2 * period:
-        period = 1
-    G = cfg.n_layers // period
-    mults = (1, 2) if period > 1 else (2, 4)
-    if G <= mults[1]:
-        return [], float(G)
-    seq = shape.seq_len or cfg.kv_chunk
-    out = []
-    for mult in mults:
-        sub_cfg = dataclasses.replace(
-            cfg,
-            n_layers=mult * period,
-            unroll_layers=True,
-            kv_chunk=max(seq, cfg.kv_chunk),
-        )
-        sub_spec = dataclasses.replace(spec, make_config=lambda s=None, c=sub_cfg: c)
-        out.append((_lm_cell(sub_spec, shape, mesh, _with_cost_cells=False), float(mult)))
-    return out, float(G)
-
-
-def _lm_cell(
-    spec: ArchSpec, shape: ShapeSpec, mesh, dtype=BF16,
-    _with_cost_cells: bool = True, optimized: bool = False,
-) -> Cell:
-    from repro.models.transformer_lm import (
-        lm_decode_step,
-        lm_init_cache,
-        lm_loss,
-        lm_param_shapes,
-        lm_prefill,
-    )
-
-    cfg = spec.make_config(shape)
-    da = data_axes(mesh)
-    if optimized:
-        # The §Perf findings as defaults: hierarchical MoE dispatch (T1),
-        # remat for train (T2), donation handled below.
-        n_data = int(np.prod([mesh.shape[a] for a in da]))
-        kw = {}
-        if cfg.is_moe:
-            kw["moe_groups"] = n_data
-        if shape.kind == "train":
-            kw["remat"] = True
-        if kw:
-            cfg = dataclasses.replace(cfg, **kw)
-    policy = sh.lm_policy(mesh, cfg)
-    cost_cells, cost_groups = (
-        _lm_cost_cells(spec, shape, mesh, cfg) if _with_cost_cells else (None, 1.0)
-    )
-    params_abs = jax.tree_util.tree_map(
-        lambda l: _sds(l.shape, dtype), lm_param_shapes(cfg)
-    )
-    p_specs = sh.lm_param_specs(params_abs, cfg, mesh)
-    p_shard = sh.tree_named(mesh, p_specs)
-
-    if shape.kind == "train":
-        opt = adamw(lr=3e-4)
-        opt_abs = jax.eval_shape(opt.init, params_abs)
-        o_shard = sh.tree_named(mesh, _opt_specs(opt_abs, p_specs))
-        tok_shard = sh.named(mesh, P(da, None))
-
-        def train_step(params, opt_state, tokens):
-            loss, grads = jax.value_and_grad(lm_loss)(params, tokens, cfg, policy)
-            new_params, new_opt = opt.update(grads, opt_state, params)
-            return new_params, new_opt, loss
-
-        tokens = _sds((shape.global_batch, shape.seq_len + 1), I32)
-        return Cell(
-            spec.arch_id, shape.name, "train_step",
-            train_step,
-            (params_abs, opt_abs, tokens),
-            (p_shard, o_shard, tok_shard),
-            (p_shard, o_shard, sh.named(mesh, P())),
-            model_flops=6.0 * cfg.active_param_count() * shape.global_batch * shape.seq_len,
-            cost_cells=cost_cells,
-            cost_groups=cost_groups,
-            donate_argnums=(0, 1) if optimized else (),
-        )
-
-    if shape.kind == "prefill":
-        tok_shard = sh.named(mesh, P(da, None))
-
-        def prefill_step(params, tokens):
-            return lm_prefill(params, tokens, cfg, policy)
-
-        tokens = _sds((shape.global_batch, shape.seq_len), I32)
-        return Cell(
-            spec.arch_id, shape.name, "serve_step",
-            prefill_step,
-            (params_abs, tokens),
-            (p_shard, tok_shard),
-            sh.named(mesh, P(da, "model")),
-            model_flops=2.0 * cfg.active_param_count() * shape.global_batch * shape.seq_len,
-            cost_cells=cost_cells,
-            cost_groups=cost_groups,
-        )
-
-    # decode: one new token with a KV cache of seq_len.
-    cache_abs = _abstract_tree(
-        jax.eval_shape(lambda: lm_init_cache(cfg, shape.global_batch, shape.seq_len, dtype))
-    )
-    cspec = sh.cache_spec(cfg, shape, mesh)
-    c_shard = jax.tree_util.tree_map(lambda _: sh.named(mesh, cspec), cache_abs)
-    n_data = int(np.prod([mesh.shape[a] for a in da]))
-    tok_spec = P(da) if shape.global_batch % n_data == 0 and shape.global_batch >= n_data else P()
-
-    def decode_step(params, cache, token, pos):
-        return lm_decode_step(params, cache, token, pos, cfg, policy)
-
-    token = _sds((shape.global_batch,), I32)
-    pos = _sds((), I32)
-    return Cell(
-        spec.arch_id, shape.name, "serve_step",
-        decode_step,
-        (params_abs, cache_abs, token, pos),
-        (p_shard, c_shard, sh.named(mesh, tok_spec), sh.named(mesh, P())),
-        (sh.named(mesh, P(tok_spec[0] if len(tok_spec) else None, "model")), c_shard),
-        model_flops=2.0 * cfg.active_param_count() * shape.global_batch,
-        note=f"KV cache {shape.seq_len} tokens, spec {cspec}",
-        cost_cells=cost_cells,
-        cost_groups=cost_groups,
-        donate_argnums=(1,) if optimized else (),   # in-place cache update
-    )
-
-
-def _opt_specs(opt_abs, p_specs):
+def _opt_specs(p_specs):
     """AdamW state {m, v, step}: m/v mirror param specs; step replicated."""
-    del opt_abs
     return {"m": p_specs, "v": p_specs, "step": P()}
+
+
+def _lead_shardings(mesh, axes, tree):
+    """Shard every leaf of ``tree`` along its leading axis over ``axes``."""
+    return jax.tree_util.tree_map(
+        lambda l: sh.named(mesh, P(axes, *([None] * (len(l.shape) - 1)))), tree
+    )
 
 
 # ======================================================================== GNN
@@ -324,28 +279,29 @@ def gnn_loss_fn(arch_id: str, cfg, policy: ShardingPolicy, n_loss_nodes: int | N
     return loss
 
 
-def _gnn_params(arch_id: str, cfg, dtype):
+def _gnn_params(arch_id: str, cfg):
+    """Abstract float32 params of a GNN arch."""
     key = jax.random.PRNGKey(0)
     if arch_id == "egnn":
         from repro.models.egnn import egnn_init
 
-        return jax.eval_shape(lambda k: egnn_init(k, cfg, dtype), key)
+        return jax.eval_shape(lambda k: egnn_init(k, cfg), key)
     if arch_id == "graphcast":
         from repro.models.graphcast import graphcast_init
 
-        return jax.eval_shape(lambda k: graphcast_init(k, cfg), key)     # float32 only
+        return jax.eval_shape(lambda k: graphcast_init(k, cfg), key)
     if arch_id == "equiformer-v2":
         from repro.models.equiformer_v2 import equiformer_init
 
-        return jax.eval_shape(lambda k: equiformer_init(k, cfg, dtype), key)
+        return jax.eval_shape(lambda k: equiformer_init(k, cfg), key)
     if arch_id == "pna":
         from repro.models.pna import pna_init
 
-        return jax.eval_shape(lambda k: pna_init(k, cfg, dtype), key)
+        return jax.eval_shape(lambda k: pna_init(k, cfg), key)
     if arch_id == "coin_gcn":
         from repro.models.gcn import gcn_init
 
-        return jax.eval_shape(lambda k: gcn_init(k, cfg, dtype), key)
+        return jax.eval_shape(lambda k: gcn_init(k, cfg), key)
     raise KeyError(arch_id)
 
 
@@ -409,8 +365,8 @@ def _gnn_flops(arch_id: str, shape: ShapeSpec, cfg, bsr_stats: dict | None = Non
     """Useful forward FLOPs (2 × MACs of the defining matmuls per arch).
 
     ``bsr_stats`` (a `repro.dist.halo.plan_blocked_shape` record) switches
-    the coin_gcn aggregation term to the blocked cost model so hillclimb and
-    the dry-run see the kernel's real nnz_blocks·B²·F work.
+    the coin_gcn aggregation term to the blocked cost model, the kernel's
+    real nnz_blocks·B²·F work.
     """
     if arch_id == "graphcast":
         from repro.models.graphcast import forward_flops
@@ -453,24 +409,15 @@ def _gnn_flops(arch_id: str, shape: ShapeSpec, cfg, bsr_stats: dict | None = Non
             else:
                 total += n * d_in * d_out + e * d_out                  # feature-first
         return 2.0 * total
-    d = getattr(cfg, "d_hidden", 512)
-    return 2.0 * L * (n * d * d + e * d)
-
-
-def _sampled_edges(shape: ShapeSpec) -> int:
-    e, frontier = 0, shape.batch_nodes
-    for f in shape.fanout:
-        e += frontier * f
-        frontier *= f
-    return e
+    raise KeyError(arch_id)
 
 
 def _shape_halo_plan(n: int, e: int, k: int, pods: int = 1):
     """Cached HaloPlan for the (n, e) shape-statistics synthetic graph.
 
-    Abstract cells have no real graph — like the rest of the dry-run they run
-    on the deterministic exact-count synthetic (DESIGN.md §5), partitioned
-    with the locality-seeking BFS+refine that keeps export sets small
+    Abstract cells have no real graph — they run on the deterministic
+    exact-count synthetic (DESIGN.md §5), partitioned with the
+    locality-seeking BFS+refine that keeps export sets small
     (DESIGN.md §7.3). The plan is memoized per (graph, k, axes) in
     `repro.dist.halo` (``pods > 1`` caches under the ("pod", "model") axes
     tuple, side by side with the flat plan), so every layer/epoch/cell over
@@ -626,8 +573,7 @@ def _gnn_halo_batch_abstract(
     adjacency tables, sized by `repro.dist.halo.plan_split_blocked_shape`
     (an interior triple over local columns plus a boundary triple over the
     halo-only columns — the overlapped schedule's pair) so no tile is ever
-    materialized for abstract cells. A legacy single-table record (no
-    "interior" key, `plan_blocked_shape`) sizes just the combined triple."""
+    materialized for abstract cells."""
     k, n_local = plan.k, plan.n_local
     if plan.is_hierarchical:
         sloc, srem, sl, rl, ew = plan.abstract_inputs()
@@ -646,12 +592,8 @@ def _gnn_halo_batch_abstract(
         batch["pos"] = _sds((k, n_local, 3), F32)
     if arch_id == "coin_gcn":
         if bsr_stats is not None:
-            if "interior" in bsr_stats:
-                parts = (("interior", "bsr_"), ("boundary", "bsr_b"))
-                tables = [(bsr_stats[tag], prefix) for tag, prefix in parts]
-            else:
-                tables = [(bsr_stats, "bsr_")]
-            for st, prefix in tables:
+            for tag, prefix in (("interior", "bsr_"), ("boundary", "bsr_b")):
+                st = bsr_stats[tag]
                 R, T, B = st["n_block_rows"], st["max_nnzb"], st["block"]
                 batch[prefix + "vals"] = _sds((k, R, T, B, B), F32)
                 batch[prefix + "cols"] = _sds((k, R, T), I32)
@@ -664,9 +606,37 @@ def _gnn_halo_batch_abstract(
     return batch
 
 
+def _train_cell(
+    spec: ArchSpec, shape: ShapeSpec, mesh, cfg, total_loss, batch_abs, batch_spec,
+    **fields,
+) -> Cell:
+    """The AdamW train step of ``total_loss`` every GNN cell compiles:
+    params replicated, ``fields`` the rest of the `Cell`."""
+    params_abs = _gnn_params(spec.arch_id, cfg)
+    p_specs = sh.replicated_specs(params_abs)
+    p_shard = sh.tree_named(mesh, p_specs)
+
+    opt = adamw(lr=1e-3)
+    opt_abs = jax.eval_shape(opt.init, params_abs)
+    o_shard = sh.tree_named(mesh, _opt_specs(p_specs))
+
+    def train_step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(total_loss)(params, batch)
+        new_params, new_opt = opt.update(grads, opt_state, params)
+        return new_params, new_opt, loss
+
+    return Cell(
+        spec.arch_id, shape.name,
+        train_step,
+        (params_abs, opt_abs, batch_abs),
+        (p_shard, o_shard, batch_spec),
+        (p_shard, o_shard, sh.named(mesh, P())),
+        **fields,
+    )
+
+
 def _gnn_halo_cell(
-    spec: ArchSpec, shape: ShapeSpec, mesh, cfg, cost_cells, dtype=F32,
-    payload: str | None = None,
+    spec: ArchSpec, shape: ShapeSpec, mesh, cfg, payload: str | None = None,
 ) -> Cell:
     """Full-graph GNN train cell over the halo schedule (the default path).
 
@@ -696,7 +666,7 @@ def _gnn_halo_cell(
     spec_axes = axes if hier else "model"
     n_raw, e_raw = _gnn_sizes(shape, pad_mult=1)
     plan = _shape_halo_plan(n_raw, e_raw, k, pods)
-    policy = sh.gnn_policy(mesh, batched=False, comm="halo", halo_payload=payload)
+    policy = sh.gnn_policy(mesh, comm="halo", halo_payload=payload)
     bsr_stats = None
     if spec.arch_id == "coin_gcn" and getattr(cfg, "backend", "segment") == "bsr":
         from repro.dist.halo import plan_split_blocked_shape
@@ -717,49 +687,11 @@ def _gnn_halo_cell(
             "boundary": st_b,
         }
 
-    params_abs = _gnn_params(spec.arch_id, cfg, dtype)
-    p_specs = sh.replicated_specs(params_abs)
-    p_shard = sh.tree_named(mesh, p_specs)
     batch_abs = _gnn_halo_batch_abstract(spec.arch_id, shape, cfg, plan, bsr_stats)
-    batch_spec = {
-        kk: sh.named(mesh, P(spec_axes, *([None] * (len(v.shape) - 1))))
-        for kk, v in batch_abs.items()
-    }
-    total_loss = halo_loss_fn(spec.arch_id, cfg, mesh, policy)
-
-    opt = adamw(lr=1e-3)
-    opt_abs = jax.eval_shape(opt.init, params_abs)
-    o_shard = sh.tree_named(mesh, _opt_specs(opt_abs, p_specs))
-
-    def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(total_loss)(params, batch)
-        new_params, new_opt = opt.update(grads, opt_state, params)
-        return new_params, new_opt, loss
-
-    note = (
-        f"full graph (hier halo pods={pods} k={k} s_loc={plan.s_loc} "
-        f"s_rem={plan.s_rem} n_local={plan.n_local})"
-        if hier else
-        f"full graph (halo k={k} s_max={plan.s_max} n_local={plan.n_local})"
-    )
-    if bsr_stats is not None:
-        note += (
-            f" bsr nnzb={bsr_stats['nnz_blocks']}"
-            f" (int={bsr_stats['interior']['nnz_blocks']}"
-            f" bnd={bsr_stats['boundary']['nnz_blocks']})"
-            f" padfrac={bsr_stats['padded_tile_fraction']:.2f}"
-        )
-    if payload:
-        note += f" payload={payload}"
-    return Cell(
-        spec.arch_id, shape.name, "train_step",
-        train_step,
-        (params_abs, opt_abs, batch_abs),
-        (p_shard, o_shard, batch_spec),
-        (p_shard, o_shard, sh.named(mesh, P())),
+    return _train_cell(
+        spec, shape, mesh, cfg, halo_loss_fn(spec.arch_id, cfg, mesh, policy),
+        batch_abs, _lead_shardings(mesh, spec_axes, batch_abs),
         model_flops=_gnn_flops(spec.arch_id, shape, cfg, bsr_stats) * 3.0,
-        note=note,
-        cost_cells=cost_cells,
         comm="halo",
         halo_plan=plan,
         bsr_stats=bsr_stats,
@@ -773,70 +705,25 @@ def _graphcast_cell(spec: ArchSpec, shape: ShapeSpec, mesh, cfg) -> Cell:
     copy of the graphs), weights replicated, the loss the shards' mean."""
     da = data_axes(mesh)
     n_data = int(np.prod([mesh.shape[a] for a in da]))
-    params_abs = _gnn_params(spec.arch_id, cfg, F32)
-    p_specs = sh.replicated_specs(params_abs)
-    p_shard = sh.tree_named(mesh, p_specs)
     batch_abs = _graphcast_batch_abstract(cfg, (n_data,))
-    batch_spec = jax.tree_util.tree_map(
-        lambda l: sh.named(mesh, P(da, *([None] * (len(l.shape) - 1)))), batch_abs)
     loss_fn = gnn_loss_fn(spec.arch_id, cfg, NO_POLICY)
 
     def total_loss(params, batch):
         return jnp.mean(jax.vmap(lambda b: loss_fn(params, b))(batch))
 
-    opt = adamw(lr=1e-3)
-    opt_abs = jax.eval_shape(opt.init, params_abs)
-    o_shard = sh.tree_named(mesh, _opt_specs(opt_abs, p_specs))
-
-    def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(total_loss)(params, batch)
-        new_params, new_opt = opt.update(grads, opt_state, params)
-        return new_params, new_opt, loss
-
-    return Cell(
-        spec.arch_id, shape.name, "train_step",
-        train_step,
-        (params_abs, opt_abs, batch_abs),
-        (p_shard, o_shard, batch_spec),
-        (p_shard, o_shard, sh.named(mesh, P())),
+    return _train_cell(
+        spec, shape, mesh, cfg, total_loss, batch_abs, _lead_shardings(mesh, da, batch_abs),
         model_flops=_gnn_flops(spec.arch_id, shape, cfg) * 3.0 * n_data,
-        note=f"data parallel, one example per shard x{n_data}",
     )
 
 
 def _gnn_cell(
-    spec: ArchSpec, shape: ShapeSpec, mesh, dtype=F32,
-    _as_cost_cell: bool = False, comm: str | None = None, optimized: bool = False,
-    payload: str | None = None,
+    spec: ArchSpec, shape: ShapeSpec, mesh,
+    comm: str | None = None, payload: str | None = None,
 ) -> Cell:
-    import dataclasses as dc
-
     cfg = spec.make_config(shape)
     if spec.arch_id == "graphcast":
         return _graphcast_cell(spec, shape, mesh, cfg)
-    if (
-        optimized and spec.arch_id == "coin_gcn" and shape.batch_nodes is None
-        and comm != "broadcast"
-    ):
-        # §Perf: full-graph GCN aggregation on the ragged blocked MXU kernel
-        # (DESIGN.md §2) instead of the segment-sum reference. Halo cells
-        # only — they thread the per-shard blocked adjacency through the
-        # batch; the broadcast escape hatch has no adjacency to feed bsr.
-        cfg = dc.replace(cfg, backend="bsr")
-    cost_cells = None
-    big = (shape.n_edges or 0) > 2_000_000
-    if (
-        spec.arch_id == "equiformer-v2" and big and not _as_cost_cell
-        and getattr(cfg, "edge_chunk", None) is None
-    ):
-        # Real program: 64 rolled chunks bound the (chunk, K, C) irrep tensor.
-        # Cost cell: the unchunked variant — its HLO is fully counted by
-        # cost_analysis (the rolled chunk scan body would be counted once).
-        flat_spec = dc.replace(spec, make_config=lambda s=None, c=cfg: c)
-        cost_cells = [
-            (_gnn_cell(flat_spec, shape, mesh, dtype, _as_cost_cell=True, comm=comm), 1.0)
-        ]
-        cfg = dc.replace(cfg, edge_chunk=-(-shape.n_edges // 64))
     da = data_axes(mesh)
     n_data = int(np.prod([mesh.shape[a] for a in da]))
     msize = mesh.shape["model"]
@@ -844,42 +731,25 @@ def _gnn_cell(
     if comm is None:
         comm = "broadcast" if sampled else "halo"
     if not sampled and comm == "halo":
-        return _gnn_halo_cell(spec, shape, mesh, cfg, cost_cells, dtype, payload=payload)
+        return _gnn_halo_cell(spec, shape, mesh, cfg, payload=payload)
     n_blocks = n_data if sampled else None
-    policy = NO_POLICY if sampled else sh.gnn_policy(mesh, batched=False, comm="broadcast")
+    policy = NO_POLICY if sampled else sh.gnn_policy(mesh, comm="broadcast")
 
-    params_abs = _gnn_params(spec.arch_id, cfg, dtype)
-    p_specs = sh.replicated_specs(params_abs)
-    p_shard = sh.tree_named(mesh, p_specs)
     loss_fn = gnn_loss_fn(
         spec.arch_id, cfg, policy, n_loss_nodes=shape.batch_nodes if sampled else None
     )
     batch_abs = _gnn_batch_abstract(spec.arch_id, shape, cfg, n_blocks, pad_mult=msize)
 
     if sampled:
-        batch_spec = jax.tree_util.tree_map(
-            lambda l: sh.named(mesh, P(da, *([None] * (len(l.shape) - 1)))), batch_abs
-        )
+        batch_spec = _lead_shardings(mesh, da, batch_abs)
 
         def total_loss(params, batch):
             losses = jax.vmap(lambda b: loss_fn(params, b))(batch)
             return jnp.mean(losses)
     else:
-        def node_or_edge_spec(l):
-            # Shard the big axis (nodes or edges) over `model`.
-            return sh.named(mesh, P("model", *([None] * (len(l.shape) - 1))))
-
-        batch_spec = jax.tree_util.tree_map(node_or_edge_spec, batch_abs)
+        # Shard the big axis (nodes or edges) over `model`.
+        batch_spec = _lead_shardings(mesh, "model", batch_abs)
         total_loss = loss_fn
-
-    opt = adamw(lr=1e-3)
-    opt_abs = jax.eval_shape(opt.init, params_abs)
-    o_shard = sh.tree_named(mesh, _opt_specs(opt_abs, p_specs))
-
-    def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(total_loss)(params, batch)
-        new_params, new_opt = opt.update(grads, opt_state, params)
-        return new_params, new_opt, loss
 
     # train = fwd + bwd ≈ 3× forward FLOPs; sampled cells run one block per
     # data shard, so FLOPs count block sizes, not the full graph.
@@ -892,116 +762,25 @@ def _gnn_cell(
         flops = _gnn_flops(spec.arch_id, blk, cfg) * 3.0
     else:
         flops = _gnn_flops(spec.arch_id, shape, cfg) * 3.0
-    return Cell(
-        spec.arch_id, shape.name, "train_step",
-        train_step,
-        (params_abs, opt_abs, batch_abs),
-        (p_shard, o_shard, batch_spec),
-        (p_shard, o_shard, sh.named(mesh, P())),
-        model_flops=flops,
-        note="sampled blocks ×%d" % (n_blocks or 1) if sampled else "full graph (broadcast)",
-        cost_cells=cost_cells,
-        comm=None if sampled else "broadcast",
-    )
-
-
-# ===================================================================== recsys
-def _recsys_cell(spec: ArchSpec, shape: ShapeSpec, mesh, dtype=F32) -> Cell:
-    from repro.models.deepfm import (
-        deepfm_forward,
-        deepfm_init,
-        deepfm_loss,
-        deepfm_retrieval,
-    )
-
-    cfg = spec.make_config(shape)
-    da = data_axes(mesh)
-    policy = sh.recsys_policy(mesh)
-    params_abs = jax.eval_shape(lambda k: deepfm_init(k, cfg, dtype), jax.random.PRNGKey(0))
-    p_specs = sh.recsys_param_specs(params_abs)
-    p_shard = sh.tree_named(mesh, p_specs)
-    mlp_flops = 2.0 * sum(
-        a * b for a, b in zip(
-            (cfg.n_fields * cfg.embed_dim, *cfg.mlp_dims), (*cfg.mlp_dims, 1)
-        )
-    )
-    per_ex = mlp_flops + 4.0 * cfg.n_fields * cfg.embed_dim
-
-    if shape.kind == "train":
-        opt = adamw(lr=1e-3)
-        opt_abs = jax.eval_shape(opt.init, params_abs)
-        o_shard = sh.tree_named(mesh, _opt_specs(opt_abs, p_specs))
-
-        def train_step(params, opt_state, ids, labels):
-            loss, grads = jax.value_and_grad(deepfm_loss)(params, ids, labels, cfg, policy)
-            new_params, new_opt = opt.update(grads, opt_state, params)
-            return new_params, new_opt, loss
-
-        ids = _sds((shape.batch, cfg.n_fields), I32)
-        labels = _sds((shape.batch,), F32)
-        bspec = sh.named(mesh, P(da, None))
-        return Cell(
-            spec.arch_id, shape.name, "train_step",
-            train_step,
-            (params_abs, opt_abs, ids, labels),
-            (p_shard, o_shard, bspec, sh.named(mesh, P(da))),
-            (p_shard, o_shard, sh.named(mesh, P())),
-            model_flops=3.0 * per_ex * shape.batch,
-        )
-
-    if shape.kind == "retrieval":
-        def retrieval_step(params, user_ids, cand_ids):
-            return deepfm_retrieval(params, user_ids, cand_ids, cfg, policy)
-
-        user = _sds((shape.batch, cfg.n_fields), I32)
-        cands = _sds((shape.batch, shape.n_candidates), I32)
-        return Cell(
-            spec.arch_id, shape.name, "serve_step",
-            retrieval_step,
-            (params_abs, user, cands),
-            (p_shard, sh.named(mesh, P(None, None)), sh.named(mesh, P(None, "model"))),
-            sh.named(mesh, P(None, "model")),
-            model_flops=2.0 * shape.batch * shape.n_candidates * cfg.d_tower,
-        )
-
-    def serve_step(params, ids):
-        return deepfm_forward(params, ids, cfg, policy)
-
-    ids = _sds((shape.batch, cfg.n_fields), I32)
-    big = shape.batch >= int(np.prod([mesh.shape[a] for a in da]))
-    bspec = sh.named(mesh, P(da, None) if big else P(None, None))
-    return Cell(
-        spec.arch_id, shape.name, "serve_step",
-        serve_step,
-        (params_abs, ids),
-        (p_shard, bspec),
-        sh.named(mesh, P(da) if big else P()),
-        model_flops=per_ex * shape.batch,
+    return _train_cell(
+        spec, shape, mesh, cfg, total_loss, batch_abs, batch_spec,
+        model_flops=flops, comm=None if sampled else "broadcast",
     )
 
 
 # ==================================================================== factory
 def build_cell(
-    spec: ArchSpec, shape: ShapeSpec, mesh, optimized: bool = False,
+    spec: ArchSpec, shape: ShapeSpec, mesh,
     comm: str | None = None, payload: str | None = None,
 ) -> Cell:
-    """optimized=True applies the §Perf findings (hierarchical MoE dispatch,
-    remat on train, param/opt/cache donation) — the beyond-paper variants
-    recorded separately from the baselines in EXPERIMENTS.md.
+    """The abstract train step of a GNN arch on ``mesh``. The aggregation
+    backend is the configuration's own (``spec.make_config(shape)``).
 
-    comm selects the full-graph GNN communication schedule: None → the
-    family default ("halo" for full-graph cells, DESIGN.md §8);
-    "broadcast" → the paper-faithful layer-output all-gather escape hatch.
-    Non-GNN families ignore it. For coin_gcn full-graph cells optimized=True
-    also switches the aggregation to ``backend="bsr"`` (the ragged blocked
-    MXU kernel, with the per-shard split blocked adjacency threaded through
-    the halo batch). payload selects the halo wire format (None/"fp32" |
-    "bf16" | "int8" — quantized boundary rows, dequantized on receive;
-    docs/communication.md "Overlapped schedule"); halo cells only."""
-    if spec.family == "lm":
-        return _lm_cell(spec, shape, mesh, optimized=optimized)
+    comm selects the full-graph communication schedule: None → "halo" for
+    full-graph cells (DESIGN.md §8); "broadcast" → the paper-faithful
+    layer-output all-gather. payload selects the halo wire format
+    (None/"fp32" | "bf16" | "int8" — quantized boundary rows, dequantized on
+    receive; docs/communication.md "Overlapped schedule"); halo cells only."""
     if spec.family == "gnn":
-        return _gnn_cell(spec, shape, mesh, comm=comm, optimized=optimized, payload=payload)
-    if spec.family == "recsys":
-        return _recsys_cell(spec, shape, mesh)
+        return _gnn_cell(spec, shape, mesh, comm=comm, payload=payload)
     raise KeyError(spec.family)

@@ -9,10 +9,10 @@ registry shape of the arch: coin_gcn then trains its published config on
 that Table-I dataset at full size (`repro.graph.generators.make_dataset`),
 graphcast (``--shape era5_1deg``) GraphCast_small on its 1° grid and
 multimesh, one seeded synthetic example per step.
-The full configs of the other archs are exercised by the dry-run
-(`repro.launch.dryrun`). The driver wires the complete substrate: synthetic
-data stream → jitted train step → AdamW → checkpointing → straggler monitor,
-and resumes from the latest checkpoint on restart.
+The other archs train their reduced configs. The driver wires the complete
+substrate: synthetic data stream → jitted train step → AdamW →
+checkpointing → straggler monitor, and resumes from the latest checkpoint
+on restart.
 """
 from __future__ import annotations
 

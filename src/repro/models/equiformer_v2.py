@@ -48,7 +48,7 @@ class EquiformerV2Config:
     n_rbf: int = 16
     cutoff: float = 5.0
     edge_chunk: int | None = None   # chunk the (E, K, C) message tensor
-    chunk_unroll: bool = False      # unroll the chunk scan (dry-run costing)
+    chunk_unroll: bool = False      # unroll the chunk scan in the lowered HLO
 
     @property
     def k_comps(self) -> int:

@@ -11,7 +11,7 @@ One config-driven implementation provides:
     entries) — the beyond-paper memory optimization for the 500k cell.
 
 Params are dicts with layer-stacked leaves (leading axis = n_layers) so the
-HLO stays small enough to compile 40 dry-run cells on the CPU backend.
+layer stack lowers as one scan and its HLO stays small.
 """
 from __future__ import annotations
 
@@ -61,9 +61,9 @@ class LMConfig:
     rope_theta: float = 10_000.0
     kv_chunk: int = 1024
     tie_embeddings: bool = True
-    # Unroll the layer scan in the lowered HLO. Needed by the dry-run:
-    # XLA's cost_analysis counts a while-loop body ONCE, so a rolled scan
-    # under-reports FLOPs/bytes/collectives by ~n_layers (EXPERIMENTS.md).
+    # Unroll the layer scan in the lowered HLO. XLA's cost_analysis counts
+    # a while-loop body ONCE, so a rolled scan under-reports
+    # FLOPs/bytes/collectives by ~n_layers.
     unroll_layers: bool = False
     # Rematerialize layer activations in backward (jax.checkpoint on the
     # scan body): trades recompute FLOPs for peak-memory (§Perf lever).
@@ -172,7 +172,7 @@ def lm_init(key: jax.Array, cfg: LMConfig, dtype=jnp.float32) -> dict:
 
 
 def lm_param_shapes(cfg: LMConfig, dtype=jnp.float32):
-    """ShapeDtypeStruct pytree — dry-run lowering without allocation."""
+    """ShapeDtypeStruct pytree — lowering without allocation."""
     return jax.eval_shape(lambda k: lm_init(k, cfg, dtype), jax.random.PRNGKey(0))
 
 

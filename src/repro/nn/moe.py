@@ -27,9 +27,8 @@ class MoEConfig:
     # Hierarchical dispatch: sort/bucket tokens WITHIN each of `groups`
     # token groups (one per data shard). Keeps the dispatch sort local to a
     # device and turns the expert exchange into the canonical EP all-to-all
-    # of a (groups, E, C, D) buffer — the fix for the collective-bound MoE
-    # cells found in EXPERIMENTS.md §Perf. groups=1 reproduces the flat
-    # (baseline) dispatch.
+    # of a (groups, E, C, D) buffer. groups=1 reproduces the flat (baseline)
+    # dispatch.
     groups: int = 1
 
     def capacity(self, n_tokens: int) -> int:
@@ -40,7 +39,7 @@ class MoEConfig:
         # one slot per expert, so C ≥ T can never overflow. Within that
         # bound stepwise decode equals the full forward pass exactly; above
         # it capacity reverts to the Switch throughput/memory tradeoff and
-        # may drop tokens under routing imbalance (cf. hillclimb T1-c).
+        # may drop tokens under routing imbalance.
         if n_tokens <= 512:
             cap = max(cap, -(-n_tokens // 8) * 8)
         return cap

@@ -31,8 +31,8 @@ __all__ = [
 
 
 def record_exchange(plan, d_feat: int, payload: str | None = None) -> None:
-    """Runtime twin of the dry-run ``exchange`` accounting
-    (`repro.launch.dryrun.exchange_accounting`): fold one halo exchange's
+    """Runtime twin of the analytic ``exchange`` accounting
+    (`repro.launch.steps.exchange_accounting`): fold one halo exchange's
     wire model for ``plan`` at feature width ``d_feat`` into the registry.
 
     Gauges (bytes are per device per exchange, from

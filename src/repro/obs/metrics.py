@@ -1,8 +1,8 @@
 """Process-local metrics registry: labeled counters, gauges, histograms.
 
-The runtime twin of the repo's dry-run accounting (docs/observability.md):
-`repro.launch.dryrun` *predicts* wire bytes and executed tiles; the
-instrumented layers (`repro.dist.halo`, `repro.serve.graph`,
+The runtime twin of the repo's analytic accounting (docs/observability.md):
+the plans and `repro.launch.steps.exchange_accounting` *predict* wire bytes
+and executed tiles; the instrumented layers (`repro.dist.halo`, `repro.serve.graph`,
 `repro.train.loop`, `repro.dist.delta`) *measure* them at runtime and fold
 the numbers into one registry with a deterministic snapshot, so a pinned
 test can assert prediction == observation (`tests/test_obs_integration.py`).

@@ -29,15 +29,14 @@ Exactness contract (what makes cached rows reusable at all):
 
 Under that contract cache-on and cache-off produce identical logits (fp32
 tolerance) while cache-on samples strictly fewer nodes and edges per query —
-pinned by `tests/test_serve_graph.py`, reported by `repro.launch.serve` and
-`benchmarks/serve_bench.py`.
+pinned by `tests/test_serve_graph.py` and reported by `repro.launch.serve`.
 
 Batch packing is partition-aligned (`repro.core.partition`): pending queries
 are grouped by the part owning their seed so a multi-device deployment — one
 part per device, `ShardingPolicy` comm contract of DESIGN.md §7/§8 — sees
 micro-batches whose subgraphs stay inside one part and ship minimal halo
-rows. The batcher records the foreign-row count per micro-batch the same way
-PR 2's dry-run records `exchange` rows.
+rows. The batcher records the foreign-row count per micro-batch, the serving
+counterpart of a halo cell's `exchange_accounting` rows.
 """
 from __future__ import annotations
 

@@ -9,8 +9,9 @@ carried to the next step so the compressed-SGD iterates track the exact ones):
 
 ``compressed_psum_mean`` performs the data-parallel mean with int8 *wire*
 operands via a manual reduce-scatter (all_to_all) + all_gather under
-shard_map, so the dry-run's collective-bytes parsing actually observes the
-4× reduction (a float psum after local dequant would not save wire bytes).
+shard_map, so the compiled HLO's collectives
+(`repro.launch.steps.collective_bytes`) carry the 4× reduction (a float
+psum after local dequant would not save wire bytes).
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
-see 1 device (the dry-run sets its own 512-device flag in a fresh process).
+see 1 device (multi-device tests set their own flag in a fresh process).
 """
 import numpy as np
 import pytest

@@ -2,8 +2,7 @@
 
 For each of the 10 assigned architectures (+ the paper's coin_gcn):
 instantiate the REDUCED config, run one forward AND one train step on CPU,
-assert output shapes and no NaNs. Full configs are exercised only by the
-dry-run (ShapeDtypeStruct, no allocation).
+assert output shapes and no NaNs.
 """
 import dataclasses
 

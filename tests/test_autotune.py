@@ -157,10 +157,11 @@ def _worked_example():
 
 
 def test_dryrun_predicted_matches_measured_worked_example():
-    """exchange_accounting's ``predicted`` block agrees EXACTLY with the
-    measured fields on the docs/communication.md 2×4 worked example — the
-    shipped calibration contract, pinned to the documented numbers."""
-    from repro.launch.dryrun import exchange_accounting
+    """The autotuner's prediction for a cell's own config agrees EXACTLY
+    with the cell's measured ``exchange_accounting`` on the
+    docs/communication.md 2×4 worked example — the shipped calibration
+    contract, pinned to the documented numbers."""
+    from repro.launch.steps import exchange_accounting
 
     g, part = _worked_example()
     plan = build_halo_plan(part, g.edge_index, axes=("pod", "model"), pods=2)
@@ -171,13 +172,22 @@ def test_dryrun_predicted_matches_measured_worked_example():
     assert plan.flat_inter_pod_rows_crossing == 160  # (2−1)·4·40
     assert plan.overlap_fraction() == 0.6869166666666666
 
+    def predicted(payload=None, overlap=False):
+        cfg = CandidateConfig(
+            pods=plan.n_pods, backend="segment", payload=payload, overlap=overlap
+        )
+        return predict_config_cost(
+            cfg, comm_stats_from_plan(plan), d_feat=64, n_nodes=plan.n_nodes,
+            n_edges=int((plan.edge_w > 0).sum()),
+        )
+
     shape = types.SimpleNamespace(d_feat=64)
     for payload, overlap in ((None, False), ("int8", True)):
         cell = types.SimpleNamespace(
             comm="halo", halo_plan=plan, halo_payload=payload, halo_overlap=overlap
         )
         acc = exchange_accounting(cell, shape)
-        pred = acc["predicted"]
+        pred = predicted(payload, overlap)
         for f in (
             "halo_rows_per_device", "broadcast_rows_per_device", "wire_fraction",
             "halo_bytes_per_exchange", "payload", "payload_bits",
@@ -191,8 +201,9 @@ def test_dryrun_predicted_matches_measured_worked_example():
     # Pinned fp32 bytes: 374 rows × 64 feats × 4 B.
     cell = types.SimpleNamespace(comm="halo", halo_plan=plan)
     acc = exchange_accounting(cell, shape)
-    assert acc["predicted"]["halo_wire_bytes_per_exchange"] == 374 * 64 * 4
-    assert acc["predicted"]["halo_exposed_bytes_per_exchange"] == 374 * 64 * 4
+    assert acc["halo_wire_bytes_per_exchange"] == 374 * 64 * 4
+    assert predicted()["halo_wire_bytes_per_exchange"] == 374 * 64 * 4
+    assert predicted()["halo_exposed_bytes_per_exchange"] == 374 * 64 * 4
 
 
 def test_predict_config_cost_rejects_pod_mismatch():
@@ -285,7 +296,7 @@ def test_pod_map_fingerprint_distinguishes_maps():
 def test_run_autotune_record_schema_small():
     """End-to-end CLI record on a small graph: calibration block empty (the
     contract), measured improvement fields present, config JSON-round-trips
-    into the dryrun --autotune-config consumer shape."""
+    with its pods, backend and pod_map."""
     from repro.launch.autotune import run_autotune
 
     rec = run_autotune(n=2000, e=12000, k=8, pods=2, d_feat=64,

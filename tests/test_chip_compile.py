@@ -76,13 +76,14 @@ def _tables(sharding, dtype):
 
 
 def _fp32_spec():
-    """coin_gcn's registry entry with quantization off (see module doc)."""
+    """coin_gcn's registry entry on the bsr backend with quantization off
+    (see module doc)."""
     from repro.configs import get_arch
     from repro.core.quant import QuantConfig
 
     spec = get_arch("coin_gcn")
     return dataclasses.replace(spec, make_config=lambda shape=None: dataclasses.replace(
-        spec.make_config(shape), quant=QuantConfig(enabled=False)))
+        spec.make_config(shape), backend="bsr", quant=QuantConfig(enabled=False)))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -171,7 +172,7 @@ def test_coin_gcn_bsr_train_step_compiles_at_pubmed_width(one_chip, native):
     from repro.train.optimizer import adamw
 
     spec = _fp32_spec()
-    cfg = dataclasses.replace(spec.make_config(spec.shapes["pubmed"]), backend="bsr")
+    cfg = spec.make_config(spec.shapes["pubmed"])
     compiled = _compile_bsr_train_step(
         cfg, N_PUBMED, E_PUBMED + N_PUBMED, _tables(one_chip, jnp.float32), one_chip)
     assert "tpu_custom_call" in compiled.as_text()
@@ -184,7 +185,7 @@ def test_flat_halo_train_step_compiles_on_four_chips(topo, native):
 
     mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
     spec = _fp32_spec()
-    cell = build_cell(spec, spec.shapes["pubmed"], mesh, optimized=True)
+    cell = build_cell(spec, spec.shapes["pubmed"], mesh)
     assert cell.comm == "halo" and cell.halo_plan.k == 4 and cell.bsr_stats is not None
     text = cell.lower(mesh).compile().as_text()
     assert "tpu_custom_call" in text

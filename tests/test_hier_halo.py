@@ -375,8 +375,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, {SRC!r})
 import jax
 from repro.configs import get_arch
-from repro.launch.dryrun import exchange_accounting
-from repro.launch.steps import build_cell
+from repro.launch.steps import build_cell, exchange_accounting
 from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((2, 1, 4), ("pod", "data", "model"))

@@ -1,5 +1,4 @@
 """End-to-end behaviour tests for the COIN system (deliverable c)."""
-import json
 import os
 import subprocess
 import sys
@@ -30,7 +29,7 @@ def test_coin_pipeline_end_to_end():
     )
     res = optimal_ce_count(model)
     # With MEASURED probabilities the optimum sits near but above the paper's
-    # uniform-p 4×4 (higher measured p_intra favors more CEs — EXPERIMENTS.md).
+    # uniform-p 4×4 (higher measured p_intra favors more CEs).
     assert res.k_mesh in (9, 16, 25, 36)
     noc = MeshNoC(4, 4)
     traces = gcn_layer_traffic(part, [64.0])
@@ -76,8 +75,9 @@ def test_gcn_trains_to_better_than_chance():
 
 @pytest.mark.slow
 def test_dryrun_cell_smoke_subprocess():
-    """One real dry-run cell on 64 virtual devices in a fresh process
-    (device count must be set before jax init, so not in-process)."""
+    """One abstract `build_cell` cell compiled on 64 virtual devices in a
+    fresh process (device count must be set before jax init, so not
+    in-process)."""
     code = (
         "import os; os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=64';\n"
         "import sys; sys.path.insert(0, %r)\n"
@@ -124,28 +124,6 @@ def test_compressed_psum_subprocess():
         [sys.executable, "-c", code], capture_output=True, text=True, timeout=300
     )
     assert "PSUM_OK" in out.stdout, out.stderr[-1500:]
-
-
-def test_dryrun_results_complete_if_present():
-    """If the base 16x16 sweep has been run, every assigned cell must be OK
-    or a documented SKIP (the multi-pod dry-run contract). A results file
-    that only holds tagged variant records (e.g. '+opt+bf16' re-runs) is a
-    resumable file whose base sweep has NOT been executed yet — the same
-    skip as no file at all, not a failure. Normalizes both results schemas
-    (v1 bare list, v2 wrapper) inline rather than importing
-    `repro.launch.dryrun.load_results`: that module pins XLA_FLAGS to 512
-    host devices at import, which must not leak into this process's env."""
-    path = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun.json")
-    if not os.path.exists(path):
-        pytest.skip("dry-run sweep not yet executed")
-    data = json.load(open(path))
-    recs = data.get("records", []) if isinstance(data, dict) else data
-    singles = [r for r in recs if r["mesh"] == "16x16"]
-    if not singles:
-        pytest.skip("base 16x16 dry-run sweep not yet executed")
-    assert len(singles) >= 40
-    bad = [r for r in singles if r["status"] == "FAIL"]
-    assert not bad, [(r["arch"], r["shape"], r.get("error")) for r in bad]
 
 
 def test_train_shape_path_casts_float16_features_on_host(monkeypatch):

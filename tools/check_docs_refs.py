@@ -8,15 +8,17 @@ Two passes over README.md, DESIGN.md, and every ``docs/*.md``:
    package-relative paths are resolved against a small set of candidate
    roots (repo root, src/repro, docs, benchmarks, examples, tests, tools),
    matching how the docs abbreviate paths (`train/elastic.py` ==
-   `src/repro/train/elastic.py`). Paths under generated directories
-   (results/) are exempt: they legitimately do not exist in a fresh
-   checkout.
+   `src/repro/train/elastic.py`). A bare ``BENCH_*.json`` resolves to its
+   pinned copy in benchmarks/baselines/ (the root copy is written at run
+   time). Paths under generated directories (results/) are exempt: they
+   legitimately do not exist in a fresh checkout.
 2. **Symbol references** (``docs/*.md`` only — the deep guides that rot
    fastest) — every backtick-quoted Python-identifier-looking token
    (``build_halo_plan``, ``HaloPlan``, ``repro.dist.halo`` …) must appear
-   somewhere in the source tree (src/, benchmarks/, examples/, tests/,
-   tools/), each dotted component checked as a whole word. A renamed or
-   deleted symbol fails CI instead of silently rotting the guide.
+   somewhere in the source tree (src/, bench/, benchmarks/, examples/,
+   tests/, tools/) or name one of its modules, each dotted component
+   checked as a whole word. A renamed or deleted symbol fails CI instead of
+   silently rotting the guide.
 
     python tools/check_docs_refs.py
 """
@@ -29,8 +31,10 @@ import sys
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 ARCH_DOCS = ("README.md", "DESIGN.md")
 GENERATED = ("results/",)
-CANDIDATE_ROOTS = ("", "src/repro", "docs", "benchmarks", "examples", "tests", "tools")
-SOURCE_DIRS = ("src", "benchmarks", "examples", "tests", "tools")
+CANDIDATE_ROOTS = (
+    "", "src/repro", "docs", "benchmarks", "benchmarks/baselines", "examples", "tests", "tools",
+)
+SOURCE_DIRS = ("src", "bench", "benchmarks", "examples", "tests", "tools")
 TOKEN = re.compile(r"[\w.\-/]+\.(?:py|md|yml|yaml|toml|txt|json)\b")
 # `code`-quoted tokens that look like Python identifiers or dotted paths
 # (pure identifier chars, starting with a letter/underscore, no slashes).
@@ -54,6 +58,7 @@ def source_text() -> str:
     chunks = []
     for d in SOURCE_DIRS:
         for path in sorted((ROOT / d).rglob("*.py")):
+            chunks.append(".".join(path.relative_to(ROOT).with_suffix("").parts))
             chunks.append(path.read_text(encoding="utf-8"))
     return "\n".join(chunks)
 
